@@ -378,11 +378,11 @@ def kernel_bench_phase(args, reps: int = 3) -> dict:
     rng = np.random.default_rng(0)
     P = B * maxp + 1
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, psize, KH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, psize, KH, D)), jnp.float32)
-    kq, ks = quantize_int8(kp, axis=(1, 3))
-    vq, vs = quantize_int8(vp, axis=(1, 3))
-    ks, vs = ks[:, 0, :, 0], vs[:, 0, :, 0]
+    kp = jnp.asarray(rng.normal(size=(P, KH, psize, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KH, psize, D)), jnp.float32)
+    kq, ks = quantize_int8(kp, axis=(2, 3))
+    vq, vs = quantize_int8(vp, axis=(2, 3))
+    ks, vs = ks[:, :, 0, 0], vs[:, :, 0, 0]
     bt = np.zeros((B, maxp), np.int32)
     for b in range(B):                       # every row at full context
         bt[b] = 1 + b * maxp + np.arange(maxp)

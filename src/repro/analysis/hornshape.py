@@ -55,21 +55,21 @@ from repro.analysis.symbolic import (AnalysisError, FakeArray, Interp,
 def _paged_entries() -> List[dict]:
     def decode(B, H, KH, D, psize, P, maxp, **kw):
         args = [FakeArray((B, H, D)),
-                FakeArray((P, psize, KH, D), kw.pop("kv_dtype", "bfloat16")),
-                FakeArray((P, psize, KH, D), "bfloat16"),
+                FakeArray((P, KH, psize, D), kw.pop("kv_dtype", "bfloat16")),
+                FakeArray((P, KH, psize, D), "bfloat16"),
                 Table("block_tables", (B, maxp), 0, P - 1),
                 Table("lengths", (B,), 0, maxp * psize)]
         if kw.pop("quantized", False):
-            args[1] = FakeArray((P, psize, KH, D), "int8")
-            args[2] = FakeArray((P, psize, KH, D), "int8")
+            args[1] = FakeArray((P, KH, psize, D), "int8")
+            args[2] = FakeArray((P, KH, psize, D), "int8")
             kw["k_scale"] = FakeArray((P, KH))
             kw["v_scale"] = FakeArray((P, KH))
         return args, {"scale": 0.5, **kw}
 
     def chunk(B, C, H, KH, D, psize, P, maxp, S_w=0, **kw):
         args = [FakeArray((B, C, H, D)),
-                FakeArray((P, psize, KH, D), "bfloat16"),
-                FakeArray((P, psize, KH, D), "bfloat16"),
+                FakeArray((P, KH, psize, D), "bfloat16"),
+                FakeArray((P, KH, psize, D), "bfloat16"),
                 Table("block_tables", (B, maxp), 0, P - 1),
                 Table("starts", (B,), 0, maxp * psize),
                 Table("chunk_lens", (B,), 0, C)]
@@ -270,9 +270,9 @@ def crosscheck_paged_geometry(*, batch: int, kv_heads: int, head_dim: int,
         "fn": "paged_attention", "label": "runtime-geometry",
         "build": lambda: (
             [FakeArray((batch, H, head_dim)),
-             FakeArray((num_pages, page_size, kv_heads, head_dim),
+             FakeArray((num_pages, kv_heads, page_size, head_dim),
                        "int8" if quantized else "bfloat16"),
-             FakeArray((num_pages, page_size, kv_heads, head_dim),
+             FakeArray((num_pages, kv_heads, page_size, head_dim),
                        "int8" if quantized else "bfloat16"),
              Table("block_tables", (batch, max_pages), 0, num_pages - 1),
              Table("lengths", (batch,), 0, max_pages * page_size)],

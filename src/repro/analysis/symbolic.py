@@ -847,7 +847,7 @@ class Interp:
             "VMEM": ScratchV,
             "SMEM": "pltpu.SMEM",
             "ANY": "pltpu.ANY",
-            "TPUCompilerParams": lambda **kw: dict(kw),
+            "CompilerParams": lambda **kw: dict(kw),
         }
         ft_tbl = {"partial": lambda fn, *a, **kw: Partial(fn, a, kw)}
         self.namespaces = {

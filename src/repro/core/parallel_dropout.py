@@ -87,10 +87,10 @@ def expand_mask(mask_blocks, units: int, batch: int) -> jax.Array:
     return m[:, None, :]
 
 
-def unit_mask(state: Optional[HornState], layer_idx, batch: int, units: int,
-              *, keep: Optional[float] = None, salt: int = 0,
-              block_size: Optional[int] = None):
-    """The mask a layer multiplies its hidden units by, or None in eval mode."""
+def block_mask(state: Optional[HornState], layer_idx, units: int, *,
+               keep: Optional[float] = None, salt: int = 0,
+               block_size: Optional[int] = None):
+    """A layer's [num_groups, n_blocks] group mask, or None in eval mode."""
     if state is None:
         return None
     keep = state.cfg.keep_hidden if keep is None else keep
@@ -98,8 +98,14 @@ def unit_mask(state: Optional[HornState], layer_idx, batch: int, units: int,
         return None
     key = jax.random.fold_in(state.layer_key(layer_idx), salt)
     bs = state.cfg.block_size if block_size is None else block_size
-    mb = group_block_mask(key, state.num_groups, units, keep, bs)
-    return expand_mask(mb, units, batch)
+    return group_block_mask(key, state.num_groups, units, keep, bs)
+
+
+def unit_mask(state: Optional[HornState], layer_idx, batch: int, units: int,
+              **kw):
+    """The mask a layer multiplies its hidden units by, or None in eval mode."""
+    mb = block_mask(state, layer_idx, units, **kw)
+    return None if mb is None else expand_mask(mb, units, batch)
 
 
 def input_mask(state: Optional[HornState], batch: int, units: int):

@@ -530,7 +530,7 @@ def make_page_copy_step():
     power-of-two width with (0, 0) pairs — copying the null page onto
     itself is a no-op by construction — so jit compiles one executable per
     width bucket, not per COW event.  Paged-cache leaves are
-    [num_pages, psize, KH, D] pools or [num_pages, KH] int8-mode scale
+    [num_pages, KH, psize, D] pools or [num_pages, KH] int8-mode scale
     sidecars (remainder layers), each optionally prefixed by the scanned-
     superblock [R, ...] axis — the page axis is 0 for even rank, 1 for odd,
     and scale rows travel with their pages (COW / prefix-cache publishes

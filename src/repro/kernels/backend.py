@@ -1,31 +1,25 @@
 """Kernel backend switch.
 
-  * "pallas"    — compiled pallas_call, TPU target (production).
+  * "pallas"    — compiled pallas_call, TPU target (production).  The only
+                  backend a process with a TPU runs.
   * "interpret" — pallas_call(interpret=True): the kernel body executes in
-                  Python on CPU; used by correctness tests in this container.
-  * "ref"       — pure-jnp oracle (ref.py); used by the 512-device dry-run
-                  (Pallas cannot lower to the CPU backend) and as the
-                  allclose reference.
+                  Python on CPU; used by correctness tests.
+  * "ref"       — pure-jnp oracle (ref.py); the CPU default (Pallas cannot
+                  lower to the CPU backend) and the allclose reference.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
 _BACKEND = None
 
 
+def _on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
+
+
 def default_backend() -> str:
-    env = os.environ.get("REPRO_KERNEL_BACKEND")
-    if env:
-        return env
-    try:
-        if jax.devices()[0].platform == "tpu":
-            return "pallas"
-    except Exception:
-        pass
-    return "ref"
+    return "pallas" if _on_tpu() else "ref"
 
 
 def get_backend() -> str:
@@ -35,11 +29,30 @@ def get_backend() -> str:
     return _BACKEND
 
 
+def with_reference_grad(kernel, reference):
+    """``kernel``'s outputs with ``reference``'s gradients.  The Pallas
+    kernels here are forward-only; a training step differentiates through
+    the pure-jnp oracle they are tested against."""
+    @jax.custom_vjp
+    def fn(*args):
+        return kernel(*args)
+
+    def fwd(*args):
+        return kernel(*args), args
+
+    def bwd(args, g):
+        return jax.vjp(reference, *args)[1](g)
+
+    fn.defvjp(fwd, bwd)
+    return fn
+
+
 def set_backend(name: str) -> None:
-    assert name in ("pallas", "interpret", "ref"), name
+    """Select a backend on CPU (tests switch to "interpret"); a process
+    with a TPU runs the compiled kernels only."""
+    if name not in ("pallas", "interpret", "ref"):
+        raise ValueError(f"unknown kernel backend {name!r}")
+    if name != "pallas" and _on_tpu():
+        raise ValueError(f"kernel backend {name!r} requested on a TPU")
     global _BACKEND
     _BACKEND = name
-
-
-def interpret_mode() -> bool:
-    return get_backend() == "interpret"

@@ -3,10 +3,12 @@
 The paper claims sub-model training "reduce[s] the size of model [and]
 improve[s] the computing performance"; with naive masking the dropped units
 still burn MXU cycles.  This kernel makes the claim real on TPU: the dropout
-mask is drawn per 128-wide block of output units (core/submodel.py), the mask
-value for the (group, n-block) lives in SMEM, and the whole K-loop of a
-dropped output tile is *skipped* (``pl.when``), so FLOPs and VMEM traffic
-scale with the kept fraction (~keep_rate at steady state).
+mask is drawn per 128-wide block of output units (core/submodel.py), the
+whole [G, N/bn] mask sits in SMEM as one block (the TPU compiler refuses a
+(1, 1) block of it) from which each step reads its (group, n-block) value,
+and the whole K-loop of a dropped output tile is *skipped* (``pl.when``), so
+FLOPs and VMEM traffic scale with the kept fraction (~keep_rate at steady
+state).
 
 Grid: (G, M/bm, N/bn, K/bk), K innermost (sequential accumulation in a VMEM
 scratch accumulator, fp32; the only ``arbitrary`` dimension — G/M/N tiles
@@ -25,8 +27,8 @@ f32 = jnp.float32
 
 
 def _kernel(mask_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
-    ki = pl.program_id(3)
-    mval = mask_ref[0, 0]                       # this (g, n-block)'s mask value
+    g, ni, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    mval = mask_ref[g, ni]                      # this (g, n-block)'s mask value
 
     @pl.when(ki == 0)
     def _init():
@@ -64,7 +66,7 @@ def dropout_matmul(x, w, mask_blocks, *, block_m: int = 128,
         functools.partial(_kernel, n_k=n_k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda g, mi, ni, ki: (g, ni),
+            pl.BlockSpec((G, N // bn), lambda g, mi, ni, ki: (0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bm, bk), lambda g, mi, ni, ki: (g, mi, ki)),
             pl.BlockSpec((bk, bn), lambda g, mi, ni, ki: (ki, ni)),
@@ -72,7 +74,7 @@ def dropout_matmul(x, w, mask_blocks, *, block_m: int = 128,
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, mi, ni, ki: (g, mi, ni)),
         out_shape=jax.ShapeDtypeStruct((G, M, N), f32),
         scratch_shapes=[pltpu.VMEM((bm, bn), f32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -117,7 +117,7 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
         scratch_shapes=[pltpu.VMEM((bq, D), f32),
                         pltpu.VMEM((bq, 1), f32),
                         pltpu.VMEM((bq, 1), f32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

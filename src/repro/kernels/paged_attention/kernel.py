@@ -15,6 +15,10 @@ Per-sequence KV is addressed through a block table (the vLLM technique: KV
 lives in a shared pool of fixed-size pages, so sequences of wildly different
 lengths pack the HBM densely and join/leave a batch without reshuffling).
 
+Pools are laid out [P, KH, psize, D], kv-heads ahead of the page's token
+axis, so each grid step reads a (1, 1, psize, D) block whose last two dims
+equal the pool's: the TPU compiler accepts any page size and head dim.
+
 Grid: (B, KH, ceil(maxp / pages_per_step)) — pages innermost (sequential).
 ``dimension_semantics`` marks the (slot, kv-head) dimensions ``parallel`` so
 TPU megacore splits the work across cores; only the page axis stays
@@ -40,7 +44,7 @@ compute is skipped via ``pl.when``.
 
 int8 paged KV: pass ``k_scale``/``v_scale`` ([P, KH] f32, one symmetric
 scale per (page, kv-head) — see ``optim/compression.quantize_int8`` with
-``axis=(1, 3)``) and int8 pools; each gathered page is dequantized
+``axis=(2, 3)``) and int8 pools; each gathered page is dequantized
 in-register right after the DMA, so the HBM traffic per page is ~half of
 bf16 and ~quarter of f32.
 
@@ -73,18 +77,24 @@ NEG_INF = -1e30
 # checks the index-map clamp against it symbolically.
 NULL_PAGE = 0
 
+# int8 scales [P, KH] are read in row groups of this many pages: the
+# smallest f32 block whose last two dims the TPU compiler accepts
+SCALE_ROWS = 8
+
 # (slot, kv-head) are embarrassingly parallel — megacore may split them;
 # the page axis is sequential (online-softmax carry in VMEM scratch)
 DIM_SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
-def _kv_page_specs(*, pps: int, psize: int, maxp: int, D: int, length_of,
-                   quantized: bool):
-    """One (1, psize, 1, D) K/V BlockSpec per page offset j of a grid step,
-    plus (1, 1) per-page scale specs in int8 mode.  Dead pages (past the
-    sequence's live length) are clamped to the null page 0 in the index map
-    itself, so garbage block-table entries are never dereferenced and no
-    DMA bandwidth is spent on them."""
+def _kv_page_specs(*, pps: int, psize: int, maxp: int, D: int, KH: int,
+                   length_of, quantized: bool):
+    """One (1, 1, psize, D) K/V BlockSpec per page offset j of a grid step
+    over the [P, KH, psize, D] pool, plus one (SCALE_ROWS, KH) scale spec per
+    offset in int8 mode (the row group of the [P, KH] scale array that holds
+    the page; ``_page_scale`` picks the (page, head) entry in-register).
+    Dead pages (past the sequence's live length) are clamped to the null page
+    0 in the index map itself, so garbage block-table entries are never
+    dereferenced and no DMA bandwidth is spent on them."""
     def page_of(b, p, j, refs):
         bt = refs[0]
         pg = p * pps + j
@@ -92,15 +102,26 @@ def _kv_page_specs(*, pps: int, psize: int, maxp: int, D: int, length_of,
         return jnp.where(live, bt[b, jnp.minimum(pg, maxp - 1)], NULL_PAGE)
 
     def kv_map(j):
-        return lambda b, h, p, *refs: (page_of(b, p, j, refs), 0, h, 0)
+        return lambda b, h, p, *refs: (page_of(b, p, j, refs), h, 0, 0)
 
     def sc_map(j):
-        return lambda b, h, p, *refs: (page_of(b, p, j, refs), h)
+        return lambda b, h, p, *refs: (page_of(b, p, j, refs) // SCALE_ROWS, 0)
 
-    kv = [pl.BlockSpec((1, psize, 1, D), kv_map(j)) for j in range(pps)]
-    sc = [pl.BlockSpec((1, 1), sc_map(j)) for j in range(pps)] \
+    kv = [pl.BlockSpec((1, 1, psize, D), kv_map(j)) for j in range(pps)]
+    sc = [pl.BlockSpec((SCALE_ROWS, KH), sc_map(j)) for j in range(pps)] \
         if quantized else []
     return kv, sc
+
+
+def _page_scale(sc_ref, page, h):
+    """The f32 scale of (page, kv-head h) out of its (SCALE_ROWS, KH) row
+    group, as a [1, 1] value: a masked sum, since Mosaic has no dynamic
+    lane index."""
+    blk = sc_ref[...]
+    row = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    hit = (row == page % SCALE_ROWS) & (col == h)
+    return jnp.sum(jnp.where(hit, blk, 0.0), keepdims=True)
 
 
 def _split_kv_refs(rest, *, pps: int, quantized: bool):
@@ -120,7 +141,7 @@ def _kernel(bt_ref, len_ref, q_ref, *rest, scale: float,
     k_refs, v_refs, ks_refs, vs_refs, tail = _split_kv_refs(
         rest, pps=pps, quantized=quantized)
     o_ref, acc_ref, m_ref, l_ref = tail
-    b, p = pl.program_id(0), pl.program_id(2)
+    b, h, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(p == 0)
     def _init():
@@ -136,11 +157,12 @@ def _kernel(bt_ref, len_ref, q_ref, *rest, scale: float,
         @pl.when(live)
         def _page(j=j, pg=pg):
             q = q_ref[0, 0].astype(f32)                 # [G, D]
-            k = k_refs[j][0, :, 0].astype(f32)          # [psize, D]
-            v = v_refs[j][0, :, 0].astype(f32)
+            k = k_refs[j][0, 0].astype(f32)             # [psize, D]
+            v = v_refs[j][0, 0].astype(f32)
             if quantized:                               # in-register dequant
-                k = k * ks_refs[j][0, 0]
-                v = v * vs_refs[j][0, 0]
+                page = bt_ref[b, pg]
+                k = k * _page_scale(ks_refs[j], page, h)
+                v = v * _page_scale(vs_refs[j], page, h)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=f32) * scale
             if softcap:
@@ -190,7 +212,7 @@ def _chunk_kernel(bt_ref, start_ref, clen_ref, *rest, scale: float,
         o_ref, ow_ref, acc_ref, m_ref, l_ref = tail
     else:
         o_ref, acc_ref, m_ref, l_ref = tail
-    b, p = pl.program_id(0), pl.program_id(2)
+    b, h, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(p == 0)
     def _init():
@@ -208,11 +230,12 @@ def _chunk_kernel(bt_ref, start_ref, clen_ref, *rest, scale: float,
         @pl.when(live)
         def _page(j=j, pg=pg):
             q = q_ref[0, 0].astype(f32)                 # [C * G, D]
-            k = k_refs[j][0, :, 0].astype(f32)          # [psize, D]
-            v = v_refs[j][0, :, 0].astype(f32)
+            k = k_refs[j][0, 0].astype(f32)             # [psize, D]
+            v = v_refs[j][0, 0].astype(f32)
             if quantized:                               # in-register dequant
-                k = k * ks_refs[j][0, 0]
-                v = v * vs_refs[j][0, 0]
+                page = bt_ref[b, pg]
+                k = k * _page_scale(ks_refs[j], page, h)
+                v = v * _page_scale(vs_refs[j], page, h)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=f32) * scale
             if softcap:
@@ -268,7 +291,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, starts,
                           interpret: bool = False,
                           pages_per_step: int = 1,
                           k_scale=None, v_scale=None, logit_index=None):
-    """q: [B, C, H, D] right-padded chunks; k/v_pages: [P, psize, KH, D]
+    """q: [B, C, H, D] right-padded chunks; k/v_pages: [P, KH, psize, D]
     (the chunk's own K/V already appended); block_tables: [B, maxp];
     starts/chunk_lens: [B] -> [B, C, H, D].  See paged_chunk_attention_ref
     for the contract; C == 1 reproduces ``paged_attention`` bit-for-bit.
@@ -280,7 +303,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, starts,
     switches the return to ``(out [B, C, H, D], out_win [B, S, H, D])``
     with the window rows gathered in the kernel epilogue."""
     B, C, H, D = q.shape
-    psize, KH = k_pages.shape[1], k_pages.shape[2]
+    KH, psize = k_pages.shape[1], k_pages.shape[2]
     maxp = block_tables.shape[1]
     G = H // KH
     quantized = _check_quant(k_pages, k_scale, v_scale)
@@ -297,7 +320,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, starts,
         psize=psize, grid_p=grid_p, pps=pps, C=C, G=G, quantized=quantized,
         S_w=S_w)
     kv_specs, sc_specs = _kv_page_specs(
-        pps=pps, psize=psize, maxp=maxp, D=D,
+        pps=pps, psize=psize, maxp=maxp, D=D, KH=KH,
         length_of=lambda b, refs: refs[1][b] + refs[2][b], quantized=quantized)
     q_spec = pl.BlockSpec((1, 1, C * G, D), lambda b, h, p, *refs: (b, h, 0, 0))
     out_spec = pl.BlockSpec((1, 1, C * G, D),
@@ -330,7 +353,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, starts,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
     )(*args)
@@ -351,11 +374,11 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     softcap: Optional[float] = None,
                     interpret: bool = False, pages_per_step: int = 1,
                     k_scale=None, v_scale=None):
-    """q: [B, H, D]; k/v_pages: [P, psize, KH, D]; block_tables: [B, maxp];
+    """q: [B, H, D]; k/v_pages: [P, KH, psize, D]; block_tables: [B, maxp];
     lengths: [B] -> [B, H, D].  ``pages_per_step``/``k_scale``/``v_scale``
     as in ``paged_chunk_attention``."""
     B, H, D = q.shape
-    psize, KH = k_pages.shape[1], k_pages.shape[2]
+    KH, psize = k_pages.shape[1], k_pages.shape[2]
     maxp = block_tables.shape[1]
     G = H // KH
     quantized = _check_quant(k_pages, k_scale, v_scale)
@@ -367,7 +390,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         _kernel, scale=scale, window=window, softcap=softcap,
         psize=psize, grid_p=grid_p, pps=pps, quantized=quantized)
     kv_specs, sc_specs = _kv_page_specs(
-        pps=pps, psize=psize, maxp=maxp, D=D,
+        pps=pps, psize=psize, maxp=maxp, D=D, KH=KH,
         length_of=lambda b, refs: refs[1][b], quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -389,7 +412,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=DIM_SEMANTICS),
         interpret=interpret,
     )(*args)

@@ -84,35 +84,35 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, starts,
 def paged_pool_update(pool, new, block_tables, positions):
     """Write one token per sequence into its page at ``positions``.
 
-    pool: [P, psize, KH, D]; new: [B, KH, D]; block_tables: [B, maxp];
+    pool: [P, KH, psize, D]; new: [B, KH, D]; block_tables: [B, maxp];
     positions: [B] absolute write positions.  Empty slots must point at the
     reserved null page 0 (their garbage writes land there harmlessly).
     """
-    psize = pool.shape[1]
+    psize = pool.shape[2]
     page = jnp.take_along_axis(
         block_tables, (positions // psize)[:, None], axis=1)[:, 0]
     slot = positions % psize
-    return pool.at[page, slot].set(new.astype(pool.dtype))
+    return pool.at[page, :, slot].set(new.astype(pool.dtype))
 
 
 def paged_pool_append(pool, new, block_tables, starts, chunk_lens):
     """Scatter each sequence's C-token chunk into its pages.
 
-    pool: [P, psize, KH, D]; new: [B, C, KH, D]; block_tables: [B, maxp];
+    pool: [P, KH, psize, D]; new: [B, C, KH, D]; block_tables: [B, maxp];
     starts: [B] absolute position of each chunk's first token; chunk_lens:
     [B] valid tokens per chunk.  Padding tokens (j >= chunk_len) are routed
     to the null page 0, so a partially-filled chunk never corrupts pages
     beyond the sequence's allocation.
     """
     B, C = new.shape[:2]
-    psize, maxp = pool.shape[1], block_tables.shape[1]
+    psize, maxp = pool.shape[2], block_tables.shape[1]
     pos = starts[:, None] + jnp.arange(C)[None, :]              # [B, C]
     pidx = jnp.clip(pos // psize, 0, maxp - 1)
     page = jnp.take_along_axis(block_tables, pidx, axis=1)
     valid = jnp.arange(C)[None, :] < chunk_lens[:, None]
     page = jnp.where(valid, page, NULL_PAGE)
     slot = pos % psize
-    return pool.at[page.reshape(-1), slot.reshape(-1)].set(
+    return pool.at[page.reshape(-1), :, slot.reshape(-1)].set(
         new.reshape((B * C,) + new.shape[2:]).astype(pool.dtype))
 
 
@@ -120,7 +120,7 @@ def paged_pool_append_quant(pool, scale, new, block_tables, starts,
                             chunk_lens):
     """int8 variant of ``paged_pool_append``: quantize-on-append.
 
-    pool: [P, psize, KH, D] int8; scale: [P, KH] f32 (one symmetric scale
+    pool: [P, KH, psize, D] int8; scale: [P, KH] f32 (one symmetric scale
     per (page, kv-head), ``optim/compression.quantize_int8`` semantics);
     new: [B, C, KH, D] fresh K or V in compute dtype.
 
@@ -133,7 +133,7 @@ def paged_pool_append_quant(pool, scale, new, block_tables, starts,
     and out-of-table positions fall onto the null page 0 exactly like the
     unquantized path.  Returns (pool, scale).
     """
-    P, psize, KH, D = pool.shape
+    P, KH, psize, D = pool.shape
     B, C = new.shape[:2]
     maxp = block_tables.shape[1]
     # pages a row's chunk can touch: the page holding ``start`` plus every
@@ -145,18 +145,18 @@ def paged_pool_append_quant(pool, scale, new, block_tables, starts,
     pages = jnp.take_along_axis(block_tables,
                                 jnp.clip(prel, 0, maxp - 1), axis=1)
     pages = jnp.where(pvalid, pages, NULL_PAGE)                 # [B, T]
-    got = pool[pages].astype(f32) * scale[pages][:, :, None, :, None]
+    got = pool[pages].astype(f32) * scale[pages][..., None, None]
     # splice the chunk tokens into the gathered pages at f32
     pos = starts[:, None] + jnp.arange(C)[None, :]              # [B, C]
     valid = jnp.arange(C)[None, :] < chunk_lens[:, None]
     t = pos // psize - p0[:, None]                              # [B, C]
     t = jnp.where(valid & (t >= 0) & (t < T), t, T)             # T -> dropped
     b_ix = jnp.broadcast_to(jnp.arange(B)[:, None], (B, C))
-    got = got.at[b_ix.reshape(-1), t.reshape(-1),
+    got = got.at[b_ix.reshape(-1), t.reshape(-1), :,
                  (pos % psize).reshape(-1)].set(
         new.reshape(B * C, KH, D).astype(f32), mode="drop")
-    q, nsc = quantize_int8(got, axis=(2, 4))                    # [B,T,1,KH,1]
-    pool = pool.at[pages.reshape(-1)].set(q.reshape(-1, psize, KH, D))
+    q, nsc = quantize_int8(got, axis=(3, 4))                    # [B,T,KH,1,1]
+    pool = pool.at[pages.reshape(-1)].set(q.reshape(-1, KH, psize, D))
     scale = scale.at[pages.reshape(-1)].set(nsc.reshape(-1, KH))
     # writes routed to the null page (padding / dead rows) may have raced;
     # its contents are never read as live data, but keep its scale sane

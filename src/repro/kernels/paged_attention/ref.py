@@ -25,11 +25,19 @@ NEG_INF = -1e30
 
 
 def dequantize_pages(pages, scale):
-    """int8 pool [P, psize, KH, D] + per-(page, kv-head) scale [P, KH] ->
+    """int8 pool [P, KH, psize, D] + per-(page, kv-head) scale [P, KH] ->
     f32 pool (the pure-jnp mirror of the kernel's in-register dequant)."""
     if scale is None:
         return pages
-    return pages.astype(f32) * scale[:, None, :, None]
+    return pages.astype(f32) * scale[:, :, None, None]
+
+
+def gather_pages(pages, block_tables):
+    """[P, KH, psize, D] pool + [B, maxp] block table -> the contiguous
+    [B, maxp * psize, KH, D] K or V view of every sequence."""
+    g = pages[block_tables].astype(f32)                 # [B, maxp, KH, psize, D]
+    B, maxp, KH, psize, D = g.shape
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, maxp * psize, KH, D)
 
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
@@ -39,7 +47,7 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
     """Single-token decode attention over a block-paged KV pool.
 
     q:            [B, H, D]   one query token per sequence
-    k/v_pages:    [P, psize, KH, D]  shared page pool (page 0 = null page)
+    k/v_pages:    [P, KH, psize, D]  shared page pool (page 0 = null page)
     block_tables: [B, maxp] int32    page ids per sequence, 0-padded
     lengths:      [B] int32          valid KV tokens per sequence (incl. the
                                      token just written at position len-1)
@@ -50,13 +58,12 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, lengths, *,
     k_pages = dequantize_pages(k_pages, k_scale)
     v_pages = dequantize_pages(v_pages, v_scale)
     B, H, D = q.shape
-    psize, KH = k_pages.shape[1], k_pages.shape[2]
-    maxp = block_tables.shape[1]
+    KH, psize = k_pages.shape[1], k_pages.shape[2]
     G = H // KH
-    S = maxp * psize
+    S = block_tables.shape[1] * psize
 
-    k = k_pages[block_tables].reshape(B, S, KH, D).astype(f32)
-    v = v_pages[block_tables].reshape(B, S, KH, D).astype(f32)
+    k = gather_pages(k_pages, block_tables)
+    v = gather_pages(v_pages, block_tables)
     qg = q.reshape(B, KH, G, D).astype(f32)
 
     s = jnp.einsum("bhgd,bshd->bhgs", qg, k) * scale
@@ -87,7 +94,7 @@ def paged_chunk_attention_ref(q, k_pages, v_pages, block_tables, starts,
     q:            [B, C, H, D]  a chunk of C tokens per sequence, right-padded
                   (token j of sequence b sits at absolute position
                   ``starts[b] + j``; rows with j >= chunk_lens[b] are padding)
-    k/v_pages:    [P, psize, KH, D]  shared page pool.  The chunk's own K/V
+    k/v_pages:    [P, KH, psize, D]  shared page pool.  The chunk's own K/V
                   must already be written (append-then-attend)
     block_tables: [B, maxp] int32    page ids per sequence, 0-padded
     starts:       [B] int32          KV tokens in pages *before* this chunk
@@ -104,13 +111,12 @@ def paged_chunk_attention_ref(q, k_pages, v_pages, block_tables, starts,
     k_pages = dequantize_pages(k_pages, k_scale)
     v_pages = dequantize_pages(v_pages, v_scale)
     B, C, H, D = q.shape
-    psize, KH = k_pages.shape[1], k_pages.shape[2]
-    maxp = block_tables.shape[1]
+    KH, psize = k_pages.shape[1], k_pages.shape[2]
     G = H // KH
-    S = maxp * psize
+    S = block_tables.shape[1] * psize
 
-    k = k_pages[block_tables].reshape(B, S, KH, D).astype(f32)
-    v = v_pages[block_tables].reshape(B, S, KH, D).astype(f32)
+    k = gather_pages(k_pages, block_tables)
+    v = gather_pages(v_pages, block_tables)
     qg = q.reshape(B, C, KH, G, D).astype(f32)
 
     s = jnp.einsum("bchgd,bshd->bhgcs", qg, k) * scale

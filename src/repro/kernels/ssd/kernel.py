@@ -91,7 +91,7 @@ def ssd_chunk_scan(x, dt, A, Bm, Cm, *, chunk: int = 256,
         # the recurrent state carried in VMEM scratch across chunk steps
         # makes the chunk axis sequential; (batch, head) split across
         # megacore like the attention kernels
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A.astype(f32), Bm, Cm)

@@ -19,18 +19,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig
 
 
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat shard_map: newer jax exposes ``jax.shard_map`` with
-    ``check_vma``; 0.4.x has ``jax.experimental.shard_map.shard_map`` with
-    ``check_rep``.  Model code calls this wrapper only."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """The target deployment mesh (function, so importing never inits jax)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -39,11 +27,13 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_test_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """A mesh over whatever devices exist (CPU tests: usually 1x1)."""
-    n = len(jax.devices())
-    data = min(data, n)
-    model = min(model, max(1, n // data))
-    devs = np.array(jax.devices()[: data * model]).reshape(data, model)
+    """A (data, model) mesh over the first ``data * model`` devices; raises
+    when fewer devices exist than the mesh asks for."""
+    devices = jax.devices()
+    if data * model > len(devices):
+        raise ValueError(f"mesh (data={data}, model={model}) needs "
+                         f"{data * model} devices, found {len(devices)}")
+    devs = np.array(devices[: data * model]).reshape(data, model)
     return Mesh(devs, ("data", "model"))
 
 
@@ -179,10 +169,24 @@ class ShardingCtx:
             return x
         return jax.lax.with_sharding_constraint(x, self.sharding(*axes))
 
+    def shard_kernel(self, fn, in_axes, out_axes):
+        """``fn`` (a Pallas kernel call, which GSPMD cannot partition) run
+        on each device's shard under ``shard_map``; every operand and the
+        result are laid out by their logical axes.  Identity off-mesh."""
+        if self.mesh is None:
+            return fn
+        return jax.shard_map(fn, mesh=self.mesh,
+                             in_specs=tuple(self.spec(*a) for a in in_axes),
+                             out_specs=self.spec(*out_axes), check_vma=False)
+
     @property
     def dp_size(self) -> int:
         s = axis_sizes(self.mesh)
         return s.get("data", 1) * s.get("pod", 1)
+
+    @property
+    def model_size(self) -> int:
+        return axis_sizes(self.mesh).get("model", 1)
 
 
 def null_ctx() -> ShardingCtx:

@@ -76,6 +76,7 @@ import numpy as np
 
 from repro.configs.base import HornConfig, get_model_config, list_archs, \
     reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serving import Engine, EngineConfig, EngineOOM, ModelBank, Router
 from repro.serving.observability import (Telemetry, TraceRecorder,
@@ -138,7 +139,7 @@ def make_requests(n: int, vocab_size: int, rng: np.random.Generator, *,
     return out
 
 
-def main() -> None:
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=list_archs(),
                     help="model architecture; required unless --replay "
@@ -238,16 +239,13 @@ def main() -> None:
                          "per-tick pool/block-table invariant checks.  "
                          "Pure-host overhead, excluded from bench gates; "
                          "exits 3 if any invariant alert fires.")
-    args = ap.parse_args()
+    return ap
 
-    if args.arch is None:
-        if not args.replay:
-            ap.error("--arch is required (unless --replay)")
-        args.arch = load_trace(args.replay)[1].get("arch")
-        if args.arch is None:
-            ap.error(f"--arch: {args.replay} records no arch in its "
-                     f"header meta; pass --arch explicitly")
 
+def build_engine(args):
+    """The model config, ModelBank (or None) and Engine the launcher serves
+    with: random weights from ``--seed``, at published widths under
+    ``--full-config``.  Raises SystemExit on a config the engine rejects."""
     cfg = get_model_config(args.arch)
     if not args.full_config:
         cfg = reduced(cfg)
@@ -290,6 +288,23 @@ def main() -> None:
         raise SystemExit(f"{args.arch}: {e}")
     if sanitizer is not None:
         sanitizer.attach(engine)
+    return cfg, bank, engine
+
+
+def main() -> None:
+    ap = make_parser()
+    args = ap.parse_args()
+    enable_compile_cache()
+
+    if args.arch is None:
+        if not args.replay:
+            ap.error("--arch is required (unless --replay)")
+        args.arch = load_trace(args.replay)[1].get("arch")
+        if args.arch is None:
+            ap.error(f"--arch: {args.replay} records no arch in its "
+                     f"header meta; pass --arch explicitly")
+
+    cfg, bank, engine = build_engine(args)
 
     if args.replay:
         records, meta = load_trace(args.replay)

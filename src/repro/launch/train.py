@@ -4,11 +4,12 @@
         --steps 100 --batch 8 --seq 512 [--mesh-data 1 --mesh-model 1] \
         [--topology allreduce|local_sgd] [--checkpoint-dir ckpt/]
 
-On the CPU container this runs the REAL production code path (pjit train step,
-Horn parallel dropout, deterministic pipeline, async checkpoints, preemption
-handling) on a 1x1 mesh with reduced configs — the same path the dry-run
-proves at (2, 16, 16).  ``--arch horn-mnist`` runs the paper's MNIST
-experiment through the neuron-centric engine instead.
+Runs the production code path (pjit train step, Horn parallel dropout,
+deterministic pipeline, async checkpoints, preemption handling) on a
+``--mesh-data x --mesh-model`` mesh, which must not ask for more devices
+than exist; reduced configs unless ``--full-config``.  ``--arch
+horn-mnist`` runs the paper's MNIST experiment through the neuron-centric
+engine instead.
 """
 from __future__ import annotations
 
@@ -26,6 +27,7 @@ from repro.configs.base import (HornConfig, RunConfig, ShapeConfig,
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.core import steps as S
 from repro.data.pipeline import SyntheticTokenPipeline, TokenPipelineConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.runtime.fault_tolerance import (NanGuard, PreemptionHandler,
                                            fault_tolerant_loop)
@@ -53,6 +55,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.arch == "horn-mnist":
         from repro.core.collective_trainer import train_mnist

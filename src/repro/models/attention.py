@@ -165,10 +165,9 @@ def cache_update(buf, new, pos, ctx: ShardingCtx):
             b, n.astype(b.dtype), lp, axis=1)
         return jnp.where(in_range, updated, b)
 
-    from repro.launch.mesh import shard_map
-    fn = shard_map(upd, mesh=ctx.mesh,
-                   in_specs=(buf_spec, new_spec, P()),
-                   out_specs=buf_spec, check_vma=False)
+    fn = jax.shard_map(upd, mesh=ctx.mesh,
+                       in_specs=(buf_spec, new_spec, P()),
+                       out_specs=buf_spec, check_vma=False)
     return fn(buf, new, jnp.asarray(pos, jnp.int32))
 
 
@@ -234,7 +233,7 @@ def attn_apply(params, x, cfg: ModelConfig, ctx: ShardingCtx, *,
       - decode: ``cache=(k_buf, v_buf)`` [B, S_max, KH, D] and ``cache_index``
         scalar -> one-token update, returns (out, updated cache).
       - paged (the unified serving step): ``block_tables`` [B, maxp] given,
-        ``cache`` is a (k_pages, v_pages) [P, psize, KH, D] pool pair,
+        ``cache`` is a (k_pages, v_pages) [P, KH, psize, D] pool pair,
         ``cache_index`` is a *per-sequence* [B] vector of KV tokens already
         in pages, and ``chunk_lens`` [B] counts the valid tokens of this
         call's [B, C] chunk (decode slots: 1; admitting prompts: up to C;
@@ -268,13 +267,21 @@ def attn_apply(params, x, cfg: ModelConfig, ctx: ShardingCtx, *,
         from repro.kernels.backend import get_backend
         if get_backend() != "ref" and not cross:
             # production TPU path: Pallas flash kernel ([B,H,S,D] layout)
-            from repro.kernels.flash_attention.kernel import flash_attention
-            out = flash_attention(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3), scale=scale, causal=causal,
-                window=window, softcap=cfg.attn_logit_softcap,
-                interpret=get_backend() == "interpret",
-            ).transpose(0, 2, 1, 3)
+            from repro.kernels.flash_attention.ops import flash_attention
+            # per-device heads keep their GQA grouping only when q and kv
+            # heads shard alike; sequences stay whole (causal masks)
+            hq, hkv = ("heads", "kv_heads") \
+                if ctx.rules.get("heads") == ctx.rules.get("kv_heads") \
+                else (None, None)
+            flash = ctx.shard_kernel(
+                partial(flash_attention, scale=scale, causal=causal,
+                        window=window, softcap=cfg.attn_logit_softcap),
+                in_axes=(("batch", hq, None, None),
+                         ("batch", hkv, None, None),
+                         ("batch", hkv, None, None)),
+                out_axes=("batch", hq, None, None))
+            out = flash(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                        v.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
         else:
             out = chunked_attention(
                 q, k, v, scale=scale, causal=causal and not cross,

@@ -100,40 +100,59 @@ def mlp_specs(cfg: ModelConfig):
     return specs
 
 
+def _dropout_kernel_fits(x_shape, mask_shape, d_ff: int) -> bool:
+    """Whether ``dropout_matmul``'s tiles cover this MLP call exactly, in
+    blocks the TPU compiler accepts (128-lane mask blocks)."""
+    B, S, d = x_shape
+    G, nb = mask_shape
+    rows = (B // G) * S
+    block_n = d_ff // nb
+    return (B % G == 0 and d_ff % nb == 0 and rows % min(128, rows) == 0
+            and d % min(128, d) == 0
+            and (block_n % 128 == 0 or block_n == d_ff))
+
+
 def mlp_apply(params, x, cfg: ModelConfig, ctx: ShardingCtx, *,
               hidden_mask=None, mask_blocks=None):
     """x: [B, S, d].  hidden_mask: [B, 1, ff]-broadcastable or None.
 
     ``hidden_mask`` is Horn's per-group structured neuron mask (inverted-dropout
     scaled at mask-creation time); group -> sample expansion happens upstream.
-    ``mask_blocks`` ([G, ff/block] in {0, 1/keep}) enables the block-sparse
-    Pallas path on TPU: dropped 128-blocks of hidden units are *skipped* in
-    the up/gate matmuls (kernels/dropout_matmul) — the paper's compute-saving
-    claim realized.  Semantics identical to the masked dense path.
+    ``mask_blocks`` ([G, ff/block] in {0, 1/keep}, the blocks ``hidden_mask``
+    expands) enables the block-sparse Pallas path: dropped blocks of hidden
+    units are *skipped* in the up/gate matmuls (kernels/dropout_matmul) —
+    the paper's compute-saving claim realized.  It is taken wherever the
+    kernel backend is not "ref" and the shapes tile (groups split the batch,
+    blocks split d_ff, 128-row tiles); semantics identical to the masked
+    dense path, which serves every other case.
     """
     act = ACTS[cfg.act]
     from repro.kernels.backend import get_backend
     backend = get_backend()
-    if mask_blocks is not None and backend != "ref":
-        from repro.kernels.dropout_matmul.kernel import dropout_matmul
+    if mask_blocks is not None and backend != "ref" \
+            and _dropout_kernel_fits(x.shape, mask_blocks.shape, cfg.d_ff):
+        from repro.kernels.dropout_matmul.ops import dropout_matmul
         B, S, d = x.shape
         G, nb = mask_blocks.shape
         block_n = cfg.d_ff // nb
         xg = x.reshape(G, (B // G) * S, d)
-        interp = backend == "interpret"
         # gate uses a {0,1} mask (masking *inside* the activation is wrong);
         # the 1/keep scale rides on the up projection.
         blocks01 = (mask_blocks > 0).astype(f32)
+        # each device multiplies its own groups by its own hidden blocks
+        grp = "batch" if G % ctx.dp_size == 0 else None
+        ffn = "ffn" if nb % ctx.model_size == 0 else None
+        dmm = ctx.shard_kernel(
+            partial(dropout_matmul, block_n=block_n, backend=backend),
+            in_axes=((grp, None, None), (None, ffn), (grp, ffn)),
+            out_axes=(grp, None, ffn))
         if cfg.mlp_gated:
-            up = dropout_matmul(xg, params["wi"], mask_blocks,
-                                block_n=block_n, interpret=interp)
-            gate = dropout_matmul(xg, params["wg"], blocks01,
-                                  block_n=block_n, interpret=interp)
+            up = dmm(xg, params["wi"], mask_blocks)
+            gate = dmm(xg, params["wg"], blocks01)
             h = act(gate) * up
         else:
             # act(up * s) != act(up) * s, so mask {0,1} first, scale after
-            h = act(dropout_matmul(xg, params["wi"], blocks01,
-                                   block_n=block_n, interpret=interp))
+            h = act(dmm(xg, params["wi"], blocks01))
             mask = jnp.repeat(mask_blocks, block_n, axis=-1)
             h = h * mask[:, None, :]
         h = h.astype(x.dtype).reshape(B, S, cfg.d_ff)

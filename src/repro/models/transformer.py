@@ -145,11 +145,14 @@ def _block_apply(bp, x, cfg: ModelConfig, ctx: ShardingCtx, *, kind: str,
                 mm = _mix_mask(mm, sm[:, None, None, :])
             out, aux = L.moe_apply(bp["moe"], h, cfg, ctx, hidden_mask=mm)
         else:
-            fm = pdrop.unit_mask(horn, layer_idx, B, cfg.d_ff, salt=5)
+            mb = pdrop.block_mask(horn, layer_idx, cfg.d_ff, salt=5)
+            fm = None if mb is None else pdrop.expand_mask(mb, cfg.d_ff, B)
             sf = _serve_slice(serve_masks, "ffn", layer_idx)
             if sf is not None:
                 fm = _mix_mask(fm, sf[:, None, :])         # [B,1,ff]
-            out = L.mlp_apply(bp["mlp"], h, cfg, ctx, hidden_mask=fm)
+                mb = None
+            out = L.mlp_apply(bp["mlp"], h, cfg, ctx, hidden_mask=fm,
+                              mask_blocks=mb)
         if cfg.post_sublayer_norm:
             out = L.norm_apply(bp["post_ffn_norm"], out, cfg)
         x = x + out.astype(x.dtype)
@@ -198,7 +201,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                      dtype=jnp.bfloat16):
     """Block-paged decode caches: every attention layer gets a pool of
-    ``num_pages`` fixed-size pages [P, psize, KH, D] addressed through a
+    ``num_pages`` fixed-size pages [P, KH, psize, D] addressed through a
     shared per-sequence block table (page ids are layer-agnostic: page j of
     layer 0 and page j of layer 7 belong to the same sequence).  Page 0 is
     reserved as the null page for empty decode slots.  Structured to match
@@ -217,8 +220,8 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
             raise ValueError(
                 f"paged KV cache supports attention mixers only, got {kind!r} "
                 "(SSM states are slot-resident, not paged — see ROADMAP)")
-        pools = (jnp.zeros((num_pages, page_size, kv, hd), dtype),
-                 jnp.zeros((num_pages, page_size, kv, hd), dtype))
+        pools = (jnp.zeros((num_pages, kv, page_size, hd), dtype),
+                 jnp.zeros((num_pages, kv, page_size, hd), dtype))
         if quantized:
             pools += (jnp.zeros((num_pages, kv), jnp.float32),
                       jnp.zeros((num_pages, kv), jnp.float32))

@@ -22,7 +22,7 @@ def quantize_int8(x, axis=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     ``axis`` given (a tuple of axes to reduce over): a scale per remaining
     slice, kept with ``keepdims=True`` so ``q * scale`` broadcasts back.
     The paged-KV pool uses this as the per-page-per-head variant: pools are
-    [P, psize, KH, D] and ``axis=(1, 3)`` yields a [P, 1, KH, 1] scale
+    [P, KH, psize, D] and ``axis=(2, 3)`` yields a [P, KH, 1, 1] scale
     (one f32 per (page, kv-head), stored beside the int8 pages).
     """
     xf = x.astype(f32)

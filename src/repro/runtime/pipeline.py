@@ -21,7 +21,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipelined_apply(block_fn: Callable, stage_params, x_mb, *,
@@ -68,9 +67,9 @@ def pipelined_apply(block_fn: Callable, stage_params, x_mb, *,
         return outs
 
     pspec = jax.tree.map(lambda _: P(stage_axis), stage_params)
-    fn = shard_map(stage_prog, mesh=mesh,
-                   in_specs=(pspec, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(stage_prog, mesh=mesh,
+                       in_specs=(pspec, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x_mb)
 
 

@@ -62,19 +62,6 @@ class _Variant:
     cost: Optional[dict] = field(default=None)
 
 
-def _cache_size(jitted) -> Optional[int]:
-    """Compile-cache entry count of a ``jax.jit`` callable, None when the
-    installed JAX doesn't expose it (detection then falls back to
-    first-seen-signature, which catches new variants but not flushes)."""
-    fn = getattr(jitted, "_cache_size", None)
-    if fn is None:
-        return None
-    try:
-        return int(fn())
-    except Exception:
-        return None
-
-
 class StepProfiler:
     """Wraps jitted step callables; collects compile events + variant
     cost/call stats.  One per Telemetry; the engine wraps its steps at
@@ -119,7 +106,7 @@ class StepProfiler:
             sig, label = keyer(args, kw)
             key = (name, sig, tuple(sorted(kw.items())))
             rec = self._variants.get(key)
-            before = _cache_size(jitted)
+            before = jitted._cache_size()
             t0 = self.clock()
             out = step_fn(*args, **kw)
             dur = self.clock() - t0
@@ -131,17 +118,10 @@ class StepProfiler:
                     rec.structs = jax.tree.map(
                         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                         args)
-                    rec._kw = dict(kw)
                 except Exception:
                     rec.structs = None
-                new_variant = True
-            else:
-                new_variant = False
             rec.calls += 1
-            after = _cache_size(jitted)
-            compiled = (after > before) if before is not None \
-                and after is not None else new_variant
-            if compiled:
+            if jitted._cache_size() > before:
                 self._record_compile(name, rec.label, t0, dur)
                 rec.compiles += 1
             return out
@@ -171,17 +151,17 @@ class StepProfiler:
 
     # -- cost attribution ----------------------------------------------------
     @staticmethod
-    def _extract_cost(jitted, structs, kw) -> dict:
-        """AOT-lower + compile the variant's captured arg structs and
-        pull FLOPs / bytes-accessed.  ``cost_analysis()`` returns a dict
-        on newer JAX, a one-element list of dicts on older backends."""
-        import jax  # noqa: F401  (structs already imported it)
-        lowered = jitted.lower(*structs) if not kw \
-            else jitted.lower(*structs, **kw)
-        cost = lowered.compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        cost = cost or {}
+    def _compile(rec: _Variant):
+        """AOT-lower + compile the variant's captured arg structs.  The
+        unified-step closure dispatches on a kwarg the underlying jitted
+        partial has already baked in, so lower() takes the positional
+        structs only."""
+        return rec.jitted.lower(*rec.structs).compile()
+
+    @classmethod
+    def _extract_cost(cls, rec: _Variant) -> dict:
+        """The variant's FLOPs / bytes-accessed from ``cost_analysis``."""
+        cost = cls._compile(rec).cost_analysis() or {}
         return {"flops": float(cost.get("flops", 0.0)),
                 "bytes_accessed": float(cost.get("bytes accessed", 0.0))}
 
@@ -194,18 +174,22 @@ class StepProfiler:
         for rec in self._variants.values():
             entry = {"calls": rec.calls, "compiles": rec.compiles}
             if rec.cost is None and compute and rec.structs is not None:
-                # the unified-step closure dispatches on a kwarg the
-                # underlying jitted partial has already baked in, so
-                # lower() takes the positional structs only
                 try:
-                    rec.cost = self._extract_cost(rec.jitted, rec.structs,
-                                                  {})
+                    rec.cost = self._extract_cost(rec)
                 except Exception as e:          # pragma: no cover
                     rec.cost = {"error": f"{type(e).__name__}: {e}"[:200]}
             if rec.cost:
                 entry.update(rec.cost)
             out[rec.label] = entry
         return out
+
+    def compiled_hlo(self) -> Dict[str, str]:
+        """Optimized HLO text of every observed variant, AOT-compiled from
+        its captured arg structs — what shows whether a Pallas kernel
+        (``tpu_custom_call``) is in the program the device runs."""
+        return {rec.label: self._compile(rec).as_text()
+                for rec in self._variants.values()
+                if rec.structs is not None}
 
     # -- roofline ------------------------------------------------------------
     def set_peaks(self, **peaks: float) -> None:

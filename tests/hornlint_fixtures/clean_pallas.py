@@ -48,6 +48,6 @@ def accumulating_scan(x, bt):
         out_specs=pl.BlockSpec((1, 1), lambda b, h, p: (b, h)),
         out_shape=jax.ShapeDtypeStruct((B, H), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, 8), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=DIM_SEMANTICS),
     )(bt, x)

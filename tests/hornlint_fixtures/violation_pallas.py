@@ -35,7 +35,7 @@ def carry_declared_parallel(x, bt):
         out_specs=pl.BlockSpec((1, 1), lambda b, h: (b, h)),  # HL303: arity 2
         out_shape=jax.ShapeDtypeStruct((B, H), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, 8), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # HL302: dim 2 carries the accumulator but is 'parallel'
             dimension_semantics=("parallel", "parallel", "parallel")),
     )(bt, x)
@@ -51,7 +51,7 @@ def semantics_rank_mismatch(x):
         out_specs=pl.BlockSpec((1, 1), lambda b, h, p: (b, h)),
         out_shape=jax.ShapeDtypeStruct((4, 8), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, 8), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # HL301: two entries for a rank-3 grid
             dimension_semantics=("parallel", "arbitrary")),
     )(x, x)

@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.launch.mesh import shard_map
+from jax import shard_map
 
 mesh = Mesh(jax.devices(), ("data", "model"))
 

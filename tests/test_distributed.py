@@ -174,3 +174,57 @@ def test_bubble_fraction():
     from repro.runtime.pipeline import bubble_fraction
     assert bubble_fraction(4, 16) == pytest.approx(3 / 19)
     assert bubble_fraction(1, 8) == 0
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels under a mesh: shard_map'd flash + dropout_matmul must train
+# like one device (subprocess: 4 forced host devices, interpret kernels)
+# ---------------------------------------------------------------------------
+KERNEL_MESH_PROG = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, numpy as np
+    from repro.kernels import backend as KB
+    KB.set_backend("interpret")
+    from repro.configs.base import (HornConfig, RunConfig, ShapeConfig,
+                                    get_model_config, reduced)
+    from repro.core import steps as S
+    from repro.data.pipeline import SyntheticTokenPipeline, TokenPipelineConfig
+    from repro.launch.mesh import make_test_mesh
+
+    cfg = reduced(get_model_config("qwen3-1.7b"), d_model=128, d_ff=512,
+                  head_dim=32)
+    run = RunConfig(model=cfg, shape=ShapeConfig("t", "train", 32, 8),
+                    horn=HornConfig(enabled=True, num_groups=4),
+                    optimizer="adamw", learning_rate=3e-4)
+    batch = SyntheticTokenPipeline(TokenPipelineConfig(
+        vocab_size=cfg.vocab_size, seq_len=32, global_batch=8)).batch_at(0)
+
+    def losses(shape):
+        mesh = make_test_mesh(*shape)
+        step, sh = S.make_train_step(run, mesh)
+        state = jax.jit(lambda k: S.init_state(k, run),
+                        out_shardings=sh["state"])(jax.random.key(0))
+        b = jax.device_put(batch, sh["batch"])
+        out = []
+        for _ in range(2):
+            state, m = step(state, b)
+            out.append(float(m["loss"]))
+        return out
+
+    want = losses((1, 1))
+    for shape in ((4, 1), (2, 2)):
+        np.testing.assert_allclose(losses(shape), want, rtol=1e-3)
+    try:
+        make_test_mesh(8, 1)
+    except ValueError:
+        print("KERNEL_MESH_OK")
+""")
+
+
+def test_kernels_under_mesh_match_one_device():
+    r = subprocess.run([sys.executable, "-c", KERNEL_MESH_PROG],
+                       capture_output=True, text=True, timeout=300,
+                       env={**__import__("os").environ,
+                            "PYTHONPATH": "src"})
+    assert "KERNEL_MESH_OK" in r.stdout, r.stdout + r.stderr[-3000:]

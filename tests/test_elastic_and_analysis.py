@@ -155,9 +155,8 @@ MERGE_PROG = textwrap.dedent("""
         merged, _ = merge_grads({"w": g}, "data", topo, residuals=None)
         return merged["w"]
 
-    from repro.launch.mesh import shard_map
-    fn = shard_map(run, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                   check_vma=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=P("data"),
+                       out_specs=P("data"), check_vma=False)
     out = np.asarray(fn(g_global))
     want = np.broadcast_to(np.asarray(g_global).mean(0), (4, 8))
     err = np.abs(out - want).max()

@@ -183,7 +183,7 @@ def test_attention_kernel_path_matches_ref_model():
 # dimension_semantics: megacore partitioning must not change numerics
 # ---------------------------------------------------------------------------
 def _strip_compiler_params(module, jitted):
-    """Re-trace ``jitted`` with TPUCompilerParams neutralized, restoring the
+    """Re-trace ``jitted`` with CompilerParams neutralized, restoring the
     module and jit cache afterwards — the with/without outputs must match
     bitwise (dimension_semantics only licenses megacore partitioning; it
     never reorders the per-step op sequence)."""
@@ -191,13 +191,13 @@ def _strip_compiler_params(module, jitted):
 
     @contextlib.contextmanager
     def ctx():
-        orig = module.pltpu.TPUCompilerParams
-        module.pltpu.TPUCompilerParams = lambda **kw: None
+        orig = module.pltpu.CompilerParams
+        module.pltpu.CompilerParams = lambda **kw: None
         jitted.clear_cache()
         try:
             yield
         finally:
-            module.pltpu.TPUCompilerParams = orig
+            module.pltpu.CompilerParams = orig
             jitted.clear_cache()
 
     return ctx()
@@ -228,3 +228,48 @@ def test_dropout_matmul_dimension_semantics_no_numeric_change():
     with _strip_compiler_params(DK, DK.dropout_matmul):
         without = DK.dropout_matmul(x, w, mask, block_n=128, interpret=True)
     assert np.array_equal(np.asarray(with_sem), np.asarray(without))
+
+
+# ---------------------------------------------------------------------------
+# forward-only kernels take the reference's gradients; backend selection
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kernel", ["flash", "dropout"])
+def test_kernel_gradients_are_the_references(kernel):
+    from repro.kernels.dropout_matmul import ops as DO
+    from repro.kernels.flash_attention import ops as FO
+
+    rng = np.random.default_rng(19)
+    if kernel == "flash":
+        args = [jnp.asarray(rng.normal(size=s), jnp.float32)
+                for s in ((1, 4, 64, 32), (1, 2, 64, 32), (1, 2, 64, 32))]
+        fn = lambda backend: lambda *a: FO.flash_attention(
+            *a, scale=0.2, block_q=32, block_k=32, backend=backend)
+    else:
+        args = [jnp.asarray(rng.normal(size=(2, 8, 64)), jnp.float32),
+                jnp.asarray(rng.normal(size=(64, 256)), jnp.float32),
+                jnp.asarray([[2.0, 0.0], [0.0, 2.0]], jnp.float32)]
+        fn = lambda backend: lambda *a: DO.dropout_matmul(
+            *a, block_n=128, backend=backend)
+    nargs = tuple(range(len(args)))
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    got = jax.grad(loss(fn("interpret")), argnums=nargs)(*args)
+    want = jax.grad(loss(fn("ref")), argnums=nargs)(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=1e-4, rtol=1e-4)
+
+
+def test_backend_on_a_tpu_is_pallas_only(monkeypatch):
+    """A process with a TPU runs the compiled kernels: the default is
+    "pallas" and no other backend can be selected."""
+    from repro.kernels import backend as KB
+
+    monkeypatch.setattr(KB, "_on_tpu", lambda: True)
+    assert KB.default_backend() == "pallas"
+    for name in ("ref", "interpret"):
+        with pytest.raises(ValueError, match="on a TPU"):
+            KB.set_backend(name)
+    monkeypatch.setattr(KB, "_on_tpu", lambda: False)
+    assert KB.default_backend() == "ref"
+    with pytest.raises(ValueError, match="unknown"):
+        KB.set_backend("cuda")

@@ -184,8 +184,8 @@ def test_paged_attention_kernel_vs_ref(B, H, KH, D, psize, maxp, variant):
     rng = np.random.default_rng((B, H, KH, psize, vid))
     P = B * maxp + 1
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, psize, KH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, psize, KH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KH, psize, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KH, psize, D)), jnp.float32)
     # each seq owns a disjoint page range; lengths straddle page boundaries
     bt = np.zeros((B, maxp), np.int32)
     lengths = np.zeros((B,), np.int32)
@@ -209,7 +209,7 @@ def test_paged_attention_kernel_vs_ref(B, H, KH, D, psize, maxp, variant):
 def test_paged_attention_empty_slot_emits_zeros():
     B, H, KH, D, psize, maxp = 2, 2, 2, 16, 8, 2
     rng = np.random.default_rng(0)
-    kp = jnp.asarray(rng.normal(size=(5, psize, KH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(5, KH, psize, D)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
     bt = jnp.asarray([[1, 2], [0, 0]], jnp.int32)
     ln = jnp.asarray([11, 0], jnp.int32)
@@ -236,8 +236,8 @@ def test_paged_chunk_attention_kernel_vs_ref(B, H, KH, D, maxp, C, variant):
     rng = np.random.default_rng((B, H, KH, C, vid))
     P = B * maxp + 1
     q = jnp.asarray(rng.normal(size=(B, C, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, PSIZE, KH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, PSIZE, KH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KH, PSIZE, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KH, PSIZE, D)), jnp.float32)
     # each seq owns a disjoint page range; chunks start mid-page, straddle
     # page boundaries, and one row is a partial chunk (right-padded)
     bt = np.zeros((B, maxp), np.int32)
@@ -271,8 +271,8 @@ def test_paged_chunk_attention_c1_bitwise_matches_decode():
     rng = np.random.default_rng(7)
     P = B * maxp + 1
     q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, psize, KH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, psize, KH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KH, psize, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KH, psize, D)), jnp.float32)
     bt = np.zeros((B, maxp), np.int32)
     lengths = np.zeros((B,), np.int32)
     for b in range(B):
@@ -292,7 +292,7 @@ def test_paged_chunk_attention_c1_bitwise_matches_decode():
 
 def test_paged_pool_append_scatter():
     psize = 4
-    pool = jnp.zeros((6, psize, 2, 8), jnp.float32)
+    pool = jnp.zeros((6, 2, psize, 8), jnp.float32)
     new = jnp.arange(2 * 5 * 2 * 8, dtype=jnp.float32).reshape(2, 5, 2, 8)
     bt = jnp.asarray([[1, 2, 0], [3, 4, 0]], jnp.int32)
     # seq 0: 5 valid tokens from position 2 (straddles pages 1 -> 2);
@@ -300,6 +300,7 @@ def test_paged_pool_append_scatter():
     out = np.asarray(paged_pool_append(pool, new, bt,
                                        jnp.asarray([2, 0], jnp.int32),
                                        jnp.asarray([5, 3], jnp.int32)))
+    out = out.transpose(0, 2, 1, 3)           # [P, psize, KH, D] for indexing
     n = np.asarray(new)
     assert np.array_equal(out[1, 2], n[0, 0]) and \
         np.array_equal(out[1, 3], n[0, 1])
@@ -311,11 +312,12 @@ def test_paged_pool_append_scatter():
 
 def test_paged_pool_update_scatter():
     psize = 4
-    pool = jnp.zeros((6, psize, 2, 8), jnp.float32)
+    pool = jnp.zeros((6, 2, psize, 8), jnp.float32)
     new = jnp.ones((3, 2, 8), jnp.float32) * jnp.asarray([1., 2., 3.])[:, None, None]
     bt = jnp.asarray([[1, 2], [3, 0], [0, 0]], jnp.int32)
     pos = jnp.asarray([5, 2, 0], jnp.int32)   # page 2 slot 1, page 3 slot 2, null
     out = np.asarray(paged_pool_update(pool, new, bt, pos))
+    out = out.transpose(0, 2, 1, 3)           # [P, psize, KH, D] for indexing
     assert np.all(out[2, 1] == 1.0)           # seq 0 -> 2nd page, offset 1
     assert np.all(out[3, 2] == 2.0)           # seq 1 -> 1st page, offset 2
     assert np.all(out[0, 0] == 3.0)           # empty slot lands in null page
@@ -624,8 +626,8 @@ def _chunk_case(B, H, KH, D, maxp, C, seed, *, int8=False):
     rng = np.random.default_rng(seed)
     P = B * maxp + 1
     q = jnp.asarray(rng.normal(size=(B, C, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, PSIZE, KH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, PSIZE, KH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KH, PSIZE, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KH, PSIZE, D)), jnp.float32)
     bt = np.zeros((B, maxp), np.int32)
     starts = np.zeros((B,), np.int32)
     clens = np.zeros((B,), np.int32)
@@ -636,10 +638,10 @@ def _chunk_case(B, H, KH, D, maxp, C, seed, *, int8=False):
         bt[b, :npg] = 1 + b * maxp + np.arange(npg)
     scales = {}
     if int8:
-        kq, ks = quantize_int8(kp, axis=(1, 3))
-        vq, vs = quantize_int8(vp, axis=(1, 3))
+        kq, ks = quantize_int8(kp, axis=(2, 3))
+        vq, vs = quantize_int8(vp, axis=(2, 3))
         kp, vp = kq, vq
-        scales = dict(k_scale=ks[:, 0, :, 0], v_scale=vs[:, 0, :, 0])
+        scales = dict(k_scale=ks[:, :, 0, 0], v_scale=vs[:, :, 0, 0])
     return (q, kp, vp, jnp.asarray(bt), jnp.asarray(starts),
             jnp.asarray(clens)), scales
 
@@ -680,8 +682,8 @@ def test_paged_decode_pages_per_step_bitwise():
     rng = np.random.default_rng(11)
     P = B * maxp + 1
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, psize, KH, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, psize, KH, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KH, psize, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KH, psize, D)), jnp.float32)
     bt = np.zeros((B, maxp), np.int32)
     lengths = np.asarray([psize * maxp, 5, psize + 1], np.int32)
     for b in range(B):
@@ -762,20 +764,20 @@ def test_paged_pool_append_quant_matches_f32_within_scale():
 
     psize, KH, D = 4, 2, 8
     rng = np.random.default_rng(5)
-    fpool = jnp.asarray(rng.normal(size=(8, psize, KH, D)), jnp.float32)
-    qp, sc = quantize_int8(fpool, axis=(1, 3))
-    sc = sc[:, 0, :, 0]
+    fpool = jnp.asarray(rng.normal(size=(8, KH, psize, D)), jnp.float32)
+    qp, sc = quantize_int8(fpool, axis=(2, 3))
+    sc = sc[:, :, 0, 0]
     new = jnp.asarray(rng.normal(size=(2, 5, KH, D)), jnp.float32)
     bt = jnp.asarray([[1, 2, 3], [4, 5, 0]], jnp.int32)
     starts = jnp.asarray([2, 0], jnp.int32)
     clens = jnp.asarray([5, 3], jnp.int32)
     fref = paged_pool_append(fpool, new, bt, starts, clens)
     qpool, qsc = paged_pool_append_quant(qp, sc, new, bt, starts, clens)
-    deq = np.asarray(qpool, np.float32) * np.asarray(qsc)[:, None, :, None]
+    deq = np.asarray(qpool, np.float32) * np.asarray(qsc)[:, :, None, None]
     fref = np.asarray(fref)
     for page in (1, 2, 3, 4, 5):                    # touched pages
-        step = np.abs(fref[page]).max(axis=(0, 2)) / 127.0 + 1e-6
-        err = np.abs(deq[page] - fref[page]).max(axis=(0, 2))
+        step = np.abs(fref[page]).max(axis=(1, 2)) / 127.0 + 1e-6
+        err = np.abs(deq[page] - fref[page]).max(axis=(1, 2))
         assert (err <= step).all(), (page, err, step)
     for page in (6, 7):                             # untouched pages
         assert np.array_equal(np.asarray(qpool)[page], np.asarray(qp)[page])

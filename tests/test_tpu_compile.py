@@ -1,0 +1,95 @@
+"""The main-path Pallas kernels compile for a TPU v5e at qwen3-1.7b's widths.
+
+Nothing runs: each kernel is lowered and compiled by the TPU compiler for a
+described (not attached) ``v5e:2x2`` topology, which refuses what interpret
+mode accepts — block shapes whose last two dims break the (8, 128) tiling
+rule, too much VMEM or SMEM.  All such compiles live in this one file: the
+topology is described inside a module-scoped fixture, so only the pytest
+worker that runs this file loads the TPU compiler.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.dropout_matmul.kernel import dropout_matmul
+from repro.kernels.flash_attention.kernel import flash_attention
+from repro.kernels.paged_attention.kernel import paged_chunk_attention
+
+# qwen3-1.7b: 16 query / 8 kv heads of 128, d_model 2048, d_ff 6144;
+# the engine's default pool of 256 pages x 16 tokens, 8 slots
+B, H, KH, D, PSIZE, PAGES, MAXP = 8, 16, 8, 128, 16, 256, 33
+D_MODEL, D_FF, HORN_GROUPS = 2048, 6144, 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compile cache off: a
+    compile for a described chip is written to the cache but cannot be
+    read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("kv_dtype,C", [("bfloat16", 1), ("bfloat16", 16),
+                                        ("int8", 1)])
+def test_paged_chunk_attention_compiles(one_chip, kv_dtype, C):
+    int8 = kv_dtype == "int8"
+
+    def fn(q, kp, vp, bt, starts, clens, *scales):
+        sc = dict(zip(("k_scale", "v_scale"), scales))
+        return paged_chunk_attention(q, kp, vp, bt, starts, clens,
+                                     scale=D ** -0.5, **sc)
+
+    pool = ((PAGES, KH, PSIZE, D), jnp.dtype(kv_dtype))
+    shapes = [((B, C, H, D), jnp.bfloat16), pool, pool,
+              ((B, MAXP), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32)]
+    if int8:
+        shapes += [((PAGES, KH), jnp.float32)] * 2
+    _compile(fn, one_chip, *shapes)
+
+
+def test_flash_attention_compiles(one_chip):
+    S = 2048
+
+    def fn(q, k, v):
+        return flash_attention(q, k, v, scale=D ** -0.5)
+
+    _compile(fn, one_chip, ((1, H, S, D), jnp.bfloat16),
+             ((1, KH, S, D), jnp.bfloat16), ((1, KH, S, D), jnp.bfloat16))
+
+
+def test_dropout_matmul_compiles(one_chip):
+    rows = 8 * 512 // HORN_GROUPS          # batch 8 x seq 512 over 4 groups
+
+    def fn(x, w, mask):
+        return dropout_matmul(x, w, mask, block_n=128)
+
+    _compile(fn, one_chip, ((HORN_GROUPS, rows, D_MODEL), jnp.bfloat16),
+             ((D_MODEL, D_FF), jnp.bfloat16),
+             ((HORN_GROUPS, D_FF // 128), jnp.float32))
