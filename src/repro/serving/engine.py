@@ -83,7 +83,7 @@ from repro.core import steps as S
 from repro.models import transformer as T
 from repro.serving.block_table import (BlockTableMirror, marshal_i32,
                                        pow2_bucket)
-from repro.serving.kv_cache import PagePool, PagePoolOOM, kv_page_bytes
+from repro.serving.kv_cache import PagePool, PagePoolOOM
 from repro.serving.model_bank import DraftModel, ModelBank
 from repro.serving.observability import EngineStats, Telemetry
 from repro.serving.router import Router
@@ -92,6 +92,8 @@ from repro.serving.scheduler import (EnsembleGroup, FCFSScheduler, Request,
 from repro.serving.speculative import DraftRunner
 
 COMBINES = ("mean_logit", "majority_vote")
+
+_annotate = jax.profiler.TraceAnnotation
 
 
 def _unified_step_key(args, kw):
@@ -268,10 +270,6 @@ class Engine:
         # readable/writable as a plain engine attribute
         self.stats = EngineStats()
         self._evictions_base = 0         # pool evictions at last reset
-        # estimated HBM bytes one tick's paged attention reads per live
-        # KV page across all layers (roofline gauges; see kv_page_bytes)
-        self._kv_bytes_per_page = cfg.num_layers * kv_page_bytes(
-            ecfg.page_size, cfg.num_kv_heads, cfg.head_dim, ecfg.kv_dtype)
         # stamp the tuning knobs into exported traces + metrics snapshots
         # — two traces from differently-configured engines must be
         # distinguishable without filenames
@@ -633,181 +631,233 @@ class Engine:
         Returns the requests that finished this tick.  Pass ``tick_clock``
         (a zero-arg callable on the same epoch as ``now``) for an honest
         post-tick timestamp; without it every event in the tick shares
-        ``now``."""
+        ``now``.
+
+        The tick and each of its phases are ``jax.profiler`` trace
+        annotations (``engine:tick`` > ``engine:plan`` /
+        ``engine:host_prep`` (> ``engine:draft``) / ``engine:device_step``
+        (> ``engine:h2d`` / ``engine:dispatch`` / ``engine:sync``) /
+        ``engine:commit``), opened and closed where the ``TickTimeline``
+        marks are taken, so a profiler trace shows them on the device
+        ops' clock; outside a profiler session they cost about a
+        microsecond each.  A tick that reaches the device stamps its
+        ``engine:tick`` span with its work counts (``_tick_counts``); one
+        that returns before the device carries none."""
         now = self._clock(now)
         tick_now = tick_clock if tick_clock else (lambda: now)
         pc = time.perf_counter                    # timeline clock (µs spans)
-        m_start = pc()
-        for req in self.sched.admit(now):
-            self.cache_hit_tokens += req.num_cached_tokens
-            self.cache_eligible_tokens += req.cache_eligible_tokens
-            self.prefill_tok_saved += req.num_cached_tokens
-            self.obs.on_admit(req, now)
-        self._sample_peak()                       # admissions allocate pages
-        done = self.sched.evict_finished(tick_now())  # e.g. max_new_tokens==1
-        if not self.sched.running:
-            if self.sched.waiting:
-                # a preempted request's context can outgrow the whole pool;
-                # with nothing running and the FCFS head unadmittable even
-                # into an empty pool, the drive loop would spin forever
-                head = self.sched.waiting[0]
-                need = self._admission_need(head)
-                if need > self.pool.capacity:
+        with _annotate("engine:tick") as tick_span:
+            m_start = pc()
+            with _annotate("engine:plan"):
+                for req in self.sched.admit(now):
+                    self.cache_hit_tokens += req.num_cached_tokens
+                    self.cache_eligible_tokens += req.cache_eligible_tokens
+                    self.prefill_tok_saved += req.num_cached_tokens
+                    self.obs.on_admit(req, now)
+                self._sample_peak()               # admissions allocate pages
+                # e.g. max_new_tokens == 1
+                done = self.sched.evict_finished(tick_now())
+                if not self.sched.running:
+                    if self.sched.waiting:
+                        # a preempted request's context can outgrow the
+                        # whole pool; with nothing running and the FCFS
+                        # head unadmittable even into an empty pool, the
+                        # drive loop would spin forever
+                        head = self.sched.waiting[0]
+                        need = self._admission_need(head)
+                        if need > self.pool.capacity:
+                            # don't leak router loads on raise
+                            self._release(done)
+                            raise EngineOOM(
+                                f"request {head.id} needs {need} page(s) "
+                                f"to re-admit but the pool has only "
+                                f"{self.pool.capacity}; its context can "
+                                f"never fit — raise --pages or lower --gen")
+                    self._release(done)
+                    return done
+
+                try:
+                    entries = self._plan_tick(now)
+                except EngineOOM:
                     self._release(done)   # don't leak router loads on raise
-                    raise EngineOOM(
-                        f"request {head.id} needs {need} page(s) to "
-                        f"re-admit but the pool has only "
-                        f"{self.pool.capacity}; its context can never "
-                        f"fit — raise --pages or lower --gen")
-            self._release(done)
-            return done
+                    raise
+                self._sample_peak()           # decode growth allocates too
+                if not entries:               # nothing runnable this tick
+                    self._release(done)
+                    return done
+                m_plan = pc()
 
-        try:
-            entries = self._plan_tick(now)
-        except EngineOOM:
-            self._release(done)           # don't leak router loads on raise
-            raise
-        self._sample_peak()                       # decode growth allocates too
-        if not entries:                           # nothing runnable this tick
-            self._release(done)
-            return done
-        m_plan = pc()
-
-        # draft proposals first: one jitted draft-circuit call covering
-        # every speculating slot (catch-up chunk + on-device scan), then
-        # the drafted tokens ride the verify chunks of the parent call
-        spec_units = [(slot, e) for slot, e in entries.items()
-                      if e.draft_len > 0]
-        draft_span = ()
-        if spec_units:
-            t_draft = pc()
-            k_tick = max(e.draft_len for _, e in spec_units)
-            drafts, draft_probs = self.spec.propose(
-                [(s, e.req) for s, e in spec_units], k_tick, self._root_key)
-            for slot, e in spec_units:
-                e.tokens[1:1 + e.draft_len] = drafts[slot, :e.draft_len]
-            draft_span = (("draft", t_draft, pc()),)
-        else:
-            draft_probs = self._noprobs
-
-        B = self.ecfg.num_slots
-        C = self._chunk_bucket(max(e.chunk_len for e in entries.values()))
-        tokens = np.zeros((B, C), np.int32)
-        starts = np.zeros((B,), np.int32)
-        chunk_lens = np.zeros((B,), np.int32)
-        req_ids = np.zeros((B,), np.int32)
-        sample_steps = np.zeros((B,), np.int32)
-        submodel_ids = np.zeros((B,), np.int32)
-        seg_ids = np.arange(B, dtype=np.int32)    # solo: own segment
-        vote_flags = np.zeros((B,), bool)
-        draft_lens = np.zeros((B,), np.int32)
-        for slot, e in entries.items():
-            tokens[slot, :e.chunk_len] = e.tokens
-            starts[slot] = e.start
-            chunk_lens[slot] = e.chunk_len
-            req_ids[slot] = e.req.id
-            sample_steps[slot] = e.sample_step
-            submodel_ids[slot] = e.mask_id
-            draft_lens[slot] = e.draft_len
-            group = e.req.group
-            if group is not None:
-                seg_ids[slot] = group.leader.slot
-                if group.combine == "majority_vote":
-                    vote_flags[slot] = True       # members sample, then vote
+            with _annotate("engine:host_prep"):
+                # draft proposals first: one jitted draft-circuit call
+                # covering every speculating slot (catch-up chunk +
+                # on-device scan), then the drafted tokens ride the verify
+                # chunks of the parent call
+                spec_units = [(slot, e) for slot, e in entries.items()
+                              if e.draft_len > 0]
+                draft_span = ()
+                if spec_units:
+                    with _annotate("engine:draft"):
+                        t_draft = pc()
+                        k_tick = max(e.draft_len for _, e in spec_units)
+                        drafts, draft_probs = self.spec.propose(
+                            [(s, e.req) for s, e in spec_units], k_tick,
+                            self._root_key)
+                        for slot, e in spec_units:
+                            e.tokens[1:1 + e.draft_len] = \
+                                drafts[slot, :e.draft_len]
+                        draft_span = (("draft", t_draft, pc()),)
                 else:
-                    # mean-logit: one sampling key per group -> one draw
-                    req_ids[slot] = group.leader.id
+                    draft_probs = self._noprobs
 
-        self.ticks_nonempty += 1
-        if len({e.req.submodel_id for e in entries.values()}) > 1:
-            self.ticks_cobatched += 1
-        self._sync_block_tables()
+                B = self.ecfg.num_slots
+                C = self._chunk_bucket(
+                    max(e.chunk_len for e in entries.values()))
+                tokens = np.zeros((B, C), np.int32)
+                starts = np.zeros((B,), np.int32)
+                chunk_lens = np.zeros((B,), np.int32)
+                req_ids = np.zeros((B,), np.int32)
+                sample_steps = np.zeros((B,), np.int32)
+                submodel_ids = np.zeros((B,), np.int32)
+                seg_ids = np.arange(B, dtype=np.int32)  # solo: own segment
+                vote_flags = np.zeros((B,), bool)
+                draft_lens = np.zeros((B,), np.int32)
+                for slot, e in entries.items():
+                    tokens[slot, :e.chunk_len] = e.tokens
+                    starts[slot] = e.start
+                    chunk_lens[slot] = e.chunk_len
+                    req_ids[slot] = e.req.id
+                    sample_steps[slot] = e.sample_step
+                    submodel_ids[slot] = e.mask_id
+                    draft_lens[slot] = e.draft_len
+                    group = e.req.group
+                    if group is not None:
+                        seg_ids[slot] = group.leader.slot
+                        if group.combine == "majority_vote":
+                            vote_flags[slot] = True   # members sample, vote
+                        else:
+                            # mean-logit: one sampling key per group -> one
+                            # draw
+                            req_ids[slot] = group.leader.id
 
-        # ticks without an ensemble group skip the on-device combine
-        # entirely (static jit arg: one extra compile per bucket at most)
-        ensembles = any(e.req.group is not None for e in entries.values())
-        m_host = pc()
-        (d_tokens, d_starts, d_chunk_lens, d_req_ids, d_sample_steps,
-         d_submodel_ids, d_seg_ids, d_vote_flags, d_draft_lens) = \
-            marshal_i32(tokens, starts, chunk_lens, req_ids, sample_steps,
-                        submodel_ids, seg_ids, vote_flags, draft_lens)
-        sampled, accepted, self.cache = self._step(
-            self.params, self.cache, d_tokens, d_starts, d_chunk_lens,
-            self._bt.dev, d_req_ids, d_sample_steps, d_submodel_ids,
-            d_seg_ids, d_vote_flags, d_draft_lens, draft_probs,
-            self._root_key, ensembles=ensembles)
-        # one deliberate host pull commits the tick: both outputs in a
-        # single transfer instead of two sequential np.asarray blocks
-        sampled, accepted = \
-            jax.device_get((sampled, accepted))   # hornlint: sync-ok
-        m_dev = pc()
-        self.steps += 1
-        post = tick_now()
+                self.ticks_nonempty += 1
+                if len({e.req.submodel_id for e in entries.values()}) > 1:
+                    self.ticks_cobatched += 1
+                self._sync_block_tables()
+                counts = self._tick_counts(entries, C)
+                self.real_tokens += counts["real_tokens"]
+                self.padded_tokens += counts["padded_tokens"]
+                tick_span.set_metadata(**counts)
 
-        for slot, e in entries.items():
-            req = e.req
-            was_prefill = req.in_prefill
-            if was_prefill:
-                self.prefill_tokens += e.chunk_len
-                self.obs.on_prefill_chunk(req, post, e.start, e.chunk_len)
-            if e.draft_len:
-                self._commit_spec(slot, e, int(sampled[slot]),
-                                  int(accepted[slot]), post)
-                continue
-            # decode writes K/V too (position context_len - 1), so advance
-            # prefill_pos past every write this tick — otherwise the next
-            # generated token flips the request back into "prefill" and
-            # re-feeds one already-written token as a redundant chunk
-            req.prefill_pos = max(req.prefill_pos, e.start + e.chunk_len)
-            if was_prefill and req.page_hashes:
-                # content-index every freshly materialized full page of the
-                # publishable (namespace-uniform) region — the next request
-                # with this prefix maps the pages instead of recomputing
-                full = min(req.prefill_pos, req.publishable_end) \
-                    // self.ecfg.page_size
-                if full:
-                    self.pool.publish_prefix(req.id, req.page_hashes, full)
-            if e.record:
-                self.sched.record_token(slot, int(sampled[slot]), post)
-                self.generated_tokens += 1
-                sid = req.submodel_id
-                self.tokens_by_submodel[sid] = \
-                    self.tokens_by_submodel.get(sid, 0) + 1
-                self.obs.on_token(req, post)
+                # ticks without an ensemble group skip the on-device
+                # combine entirely (static jit arg: one extra compile per
+                # bucket at most)
+                ensembles = any(e.req.group is not None
+                                for e in entries.values())
+                m_host = pc()
+            with _annotate("engine:device_step"):
+                with _annotate("engine:h2d"):
+                    (d_tokens, d_starts, d_chunk_lens, d_req_ids,
+                     d_sample_steps, d_submodel_ids, d_seg_ids, d_vote_flags,
+                     d_draft_lens) = \
+                        marshal_i32(tokens, starts, chunk_lens, req_ids,
+                                    sample_steps, submodel_ids, seg_ids,
+                                    vote_flags, draft_lens)
+                with _annotate("engine:dispatch"):
+                    sampled, accepted, self.cache = self._step(
+                        self.params, self.cache, d_tokens, d_starts,
+                        d_chunk_lens, self._bt.dev, d_req_ids,
+                        d_sample_steps, d_submodel_ids, d_seg_ids,
+                        d_vote_flags, d_draft_lens, draft_probs,
+                        self._root_key, ensembles=ensembles)
+                with _annotate("engine:sync"):
+                    # one deliberate host pull commits the tick: both
+                    # outputs in a single transfer instead of two
+                    # sequential np.asarray blocks
+                    sampled, accepted = jax.device_get(  # hornlint: sync-ok
+                        (sampled, accepted))
+                m_dev = pc()
+            with _annotate("engine:commit"):
+                self.steps += 1
+                post = tick_now()
 
-        finished = self.sched.evict_finished(post)
-        self._release(done + finished)
-        # the tick's phase spans + per-slot device-window annotations —
-        # per-slot tuples are only built when a timeline is recording
-        if self.obs.timeline is not None:
-            slot_events = [
-                (slot, f"verify+{e.draft_len}" if e.draft_len
-                 else ("decode" if e.sample_step else "prefill"),
-                 m_host, m_dev,
-                 {"req": e.req.id, "tokens": e.chunk_len, "start": e.start})
-                for slot, e in entries.items()]
-            counters = {"used_pages": self.pool.used_pages,
-                        "cached_pages": self.pool.cached_pages,
-                        "running": len(self.sched.running),
-                        "waiting": len(self.sched.waiting)}
-        else:
-            slot_events, counters = (), None
-        # estimated KV HBM traffic of this tick's device call (roofline
-        # gauges): every live slot's paged attention walks its whole
-        # table each layer
-        kv_read_bytes = self._kv_bytes_per_page * sum(
-            self.pool.pages_for(e.req.context_len)
-            for e in entries.values())
+                for slot, e in entries.items():
+                    req = e.req
+                    was_prefill = req.in_prefill
+                    if was_prefill:
+                        self.prefill_tokens += e.chunk_len
+                        self.obs.on_prefill_chunk(req, post, e.start,
+                                                  e.chunk_len)
+                    if e.draft_len:
+                        self._commit_spec(slot, e, int(sampled[slot]),
+                                          int(accepted[slot]), post)
+                        continue
+                    # decode writes K/V too (position context_len - 1), so
+                    # advance prefill_pos past every write this tick —
+                    # otherwise the next generated token flips the request
+                    # back into "prefill" and re-feeds one already-written
+                    # token as a redundant chunk
+                    req.prefill_pos = max(req.prefill_pos,
+                                          e.start + e.chunk_len)
+                    if was_prefill and req.page_hashes:
+                        # content-index every freshly materialized full
+                        # page of the publishable (namespace-uniform)
+                        # region — the next request with this prefix maps
+                        # the pages instead of recomputing
+                        full = min(req.prefill_pos, req.publishable_end) \
+                            // self.ecfg.page_size
+                        if full:
+                            self.pool.publish_prefix(req.id,
+                                                     req.page_hashes, full)
+                    if e.record:
+                        self.sched.record_token(slot, int(sampled[slot]),
+                                                post)
+                        self.generated_tokens += 1
+                        sid = req.submodel_id
+                        self.tokens_by_submodel[sid] = \
+                            self.tokens_by_submodel.get(sid, 0) + 1
+                        self.obs.on_token(req, post)
+
+                finished = self.sched.evict_finished(post)
+                self._release(done + finished)
+                # the tick's phase spans + per-slot device-window
+                # annotations — per-slot tuples are only built when a
+                # timeline is recording
+                if self.obs.timeline is not None:
+                    slot_events = [
+                        (slot, f"verify+{e.draft_len}" if e.draft_len
+                         else ("decode" if e.sample_step else "prefill"),
+                         m_host, m_dev,
+                         {"req": e.req.id, "tokens": e.chunk_len,
+                          "start": e.start})
+                        for slot, e in entries.items()]
+                    counters = {"used_pages": self.pool.used_pages,
+                                "cached_pages": self.pool.cached_pages,
+                                "running": len(self.sched.running),
+                                "waiting": len(self.sched.waiting)}
+                else:
+                    slot_events, counters = (), None
+                m_end = pc()
         self.obs.on_tick(self.steps - 1, (m_start, m_plan, m_host, m_dev,
-                                          pc()),
+                                          m_end),
                          slot_events=slot_events, extra_spans=draft_span,
-                         counters=counters,
-                         tokens=int(sum(e.chunk_len
-                                        for e in entries.values())),
-                         t=post, used_pages=self.pool.used_pages,
-                         live_pages=self.pool.live_table_pages,
-                         kv_read_bytes=kv_read_bytes)
+                         counters=counters, counts=counts, t=post,
+                         used_pages=self.pool.used_pages,
+                         live_pages=self.pool.live_table_pages)
         return done + finished
+
+    def _tick_counts(self, entries: Dict[int, _Entry], C: int) -> dict:
+        """The work of one device tick: ``real_tokens`` the slots' chunk
+        lengths, ``prefill_tokens`` those of slots still in prefill,
+        ``padded_tokens`` the positions the step computes (every slot
+        padded to the chunk width ``chunk``), ``live_slots`` the slots
+        that carry a chunk."""
+        return {"tick": self.steps,
+                "real_tokens": sum(e.chunk_len for e in entries.values()),
+                "prefill_tokens": sum(e.chunk_len for e in entries.values()
+                                      if e.req.in_prefill),
+                "padded_tokens": self.ecfg.num_slots * C,
+                "chunk": C,
+                "live_slots": len(entries)}
 
     def _commit_spec(self, slot: int, e: _Entry, sampled: int, acc: int,
                      now: float) -> None:

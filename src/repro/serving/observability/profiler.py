@@ -22,12 +22,7 @@ silent regressions:
     Argument shape/dtype structs are captured on each variant's first
     call and the (potentially multi-second) ``lower().compile()`` for
     cost extraction is deferred to ``cost_report()`` — exit-report /
-    regression-harness time, never the tick path.
-
-Roofline context: ``set_peaks`` records the ``kernel_bench`` reference
-rates (single-layer paged-attention tok/s and KV GB/s — layers run
-sequentially, so the kernel's byte *rate* is also the model's ceiling),
-and ``roofline()`` relates achieved rates to them."""
+    regression-harness time, never the tick path."""
 from __future__ import annotations
 
 import time
@@ -75,7 +70,6 @@ class StepProfiler:
         self.compiles_post_warm = 0
         self._warm = False
         self._variants: Dict[tuple, _Variant] = {}
-        self.peaks: Dict[str, float] = {}
         # set by the owning Telemetry: routes each event to the timeline
         # span + anomaly monitor the moment the compile is observed
         self.on_compile: Optional[Callable[[CompileEvent], None]] = None
@@ -190,27 +184,6 @@ class StepProfiler:
         return {rec.label: self._compile(rec).as_text()
                 for rec in self._variants.values()
                 if rec.structs is not None}
-
-    # -- roofline ------------------------------------------------------------
-    def set_peaks(self, **peaks: float) -> None:
-        """Reference rates from ``kernel_bench`` (e.g. ``kv_gb_s=...``,
-        ``tok_s=...``); achieved-vs-peak gauges divide by these."""
-        self.peaks.update({k: float(v) for k, v in peaks.items()
-                           if v is not None})
-
-    def roofline(self, achieved: Dict[str, float]) -> dict:
-        """Relate achieved rates to the recorded peaks: for each metric
-        present in both, emit the achieved value, the peak, and the
-        fraction."""
-        out = {}
-        for k, v in achieved.items():
-            entry = {"achieved": v}
-            peak = self.peaks.get(k)
-            if peak:
-                entry["peak"] = peak
-                entry["frac"] = v / peak
-            out[k] = entry
-        return out
 
     # -- lifecycle -----------------------------------------------------------
     def summary(self) -> dict:

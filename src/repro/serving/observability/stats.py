@@ -24,6 +24,9 @@ class EngineStats:
     steps: int = 0                   # unified device ticks issued
     generated_tokens: int = 0        # committed (recorded) tokens
     prefill_tokens: int = 0          # prompt/recompute tokens streamed
+    real_tokens: int = 0             # tokens the device ticks carried
+    padded_tokens: int = 0           # positions they computed (slots x
+                                     # the tick's chunk width)
     peak_utilization: float = 0.0    # page-pool high-water mark
     bt_rows_synced: int = 0          # block-table rows re-uploaded
     ticks_nonempty: int = 0          # ticks that issued a device call

@@ -169,41 +169,27 @@ class Telemetry:
 
     # -- per-tick hook (perf_counter clock) ----------------------------------
     def on_tick(self, tick: int, marks, slot_events=(), extra_spans=(),
-                counters: Optional[dict] = None, tokens: int = 0,
-                t: float = 0.0, used_pages: Optional[int] = None,
-                live_pages=None, kv_read_bytes: int = 0) -> None:
+                counters: Optional[dict] = None,
+                counts: Optional[dict] = None, t: float = 0.0,
+                used_pages: Optional[int] = None, live_pages=None) -> None:
         """``t`` is the engine-clock tick time (alerts are stamped with
         it); ``used_pages``/``live_pages`` feed the pool-leak watchdog
         (``live_pages`` a zero-arg callable, evaluated only when due);
-        ``kv_read_bytes`` is the tick's estimated KV traffic for the
-        roofline gauges."""
+        ``counts`` is the tick's work (``Engine._tick_counts``): its
+        ``real_tokens`` feed ``tokens_per_tick``, and the timeline keeps
+        it on the tick's record."""
         dur = marks[-1] - marks[0]
         self.tick_s.observe(dur)
+        tokens = (counts or {}).get("real_tokens", 0)
         if tokens:
             self.tokens_per_tick.observe(tokens)
         if self.timeline is not None:
             self.timeline.add_tick(tick, marks, slot_events=slot_events,
                                    extra_spans=extra_spans,
-                                   counters=counters)
+                                   counters=counters, counts=counts)
         if self.anomaly is not None:
             self.anomaly.on_tick(tick, t, dur, used_pages=used_pages,
                                  live_pages=live_pages)
-        # per-tick roofline gauges: what the device achieved this tick
-        # vs. the kernel_bench reference rates (when set via the
-        # profiler); device_step phase time is marks[3] - marks[2]
-        if tokens and self.profiler is not None:
-            dev = max(marks[3] - marks[2], 1e-9)
-            r = self.registry
-            r.gauge("achieved_tok_s").set(tokens / dev)
-            if kv_read_bytes:
-                r.gauge("achieved_kv_gb_s").set(kv_read_bytes / dev / 1e9)
-            peaks = self.profiler.peaks
-            if peaks.get("tok_s"):
-                r.gauge("roofline_tok_frac").set(
-                    tokens / dev / peaks["tok_s"])
-            if peaks.get("kv_gb_s") and kv_read_bytes:
-                r.gauge("roofline_kv_frac").set(
-                    kv_read_bytes / dev / 1e9 / peaks["kv_gb_s"])
 
     # -- read side -----------------------------------------------------------
     def collect(self, engine) -> MetricsRegistry:

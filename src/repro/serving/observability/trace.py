@@ -16,6 +16,9 @@ Two recorders, two clocks, deliberately:
     queue depth).  ``to_chrome()`` emits Chrome Trace Event JSON —
     ``--trace-out trace.json`` opens directly in Perfetto
     (https://ui.perfetto.dev) or chrome://tracing.
+    ``tick_records()`` hands readers each tick's phases and work counts.
+    The engine opens the same phases as ``jax.profiler`` annotations
+    (``engine:*``), which put them on the device trace's clock.
 
 Everything here is host-side and append-only; the jitted step never
 sees any of it, so the one-device-call-per-tick invariant is untouched.
@@ -207,8 +210,9 @@ class TickTimeline:
         self._spans: List[Tuple[str, int, float, float, dict]] = []
         self._instants: List[Tuple[str, float, dict]] = []
         self._counters: List[Tuple[str, float, dict]] = []
+        # (tick, t0, t1, {phase: (t0, t1)}, counts) per device tick
+        self._ticks: List[Tuple[int, float, float, dict, dict]] = []
         self._metadata: dict = {}
-        self.ticks = 0
 
     def set_metadata(self, **kv) -> None:
         """Stamp run-level configuration (kv_dtype, pages_per_step,
@@ -222,25 +226,45 @@ class TickTimeline:
     def add_tick(self, tick: int, marks: Sequence[float],
                  slot_events: Sequence[Tuple[int, str, float, float, dict]]
                  = (), extra_spans: Sequence[Tuple[str, float, float]] = (),
-                 counters: Optional[dict] = None) -> None:
+                 counters: Optional[dict] = None,
+                 counts: Optional[dict] = None) -> None:
         """``marks`` are the 5 phase boundaries (start, after-plan,
         after-host-prep, after-device, end); ``slot_events`` are
         (slot, name, t0, t1, args) annotations; ``extra_spans`` nest
         inside the tick on the engine track (e.g. the draft call);
-        ``counters`` is a point sample for the counter track."""
+        ``counters`` is a point sample for the counter track; ``counts``
+        the tick's work (real, prefill and padded tokens, chunk width,
+        live slots), kept on its tick record."""
         if len(marks) != len(TICK_PHASES) + 1:
             raise ValueError(
                 f"need {len(TICK_PHASES) + 1} marks, got {len(marks)}")
+        phases = {}
         for name, t0, t1 in zip(TICK_PHASES, marks, marks[1:]):
             self._spans.append((name, _ENGINE_TID, t0, t1, {"tick": tick}))
+            phases[name] = (t0, t1)
         for name, t0, t1 in extra_spans:
             self._spans.append((name, _ENGINE_TID, t0, t1, {"tick": tick}))
+            phases[name] = (t0, t1)
         for slot, name, t0, t1, args in slot_events:
             self._spans.append((name, slot + 1, t0, t1,
                                 {"tick": tick, **args}))
         if counters:
             self._counters.append(("engine", marks[0], dict(counters)))
-        self.ticks += 1
+        self._ticks.append((tick, marks[0], marks[-1], phases,
+                            dict(counts or {})))
+
+    @property
+    def ticks(self) -> int:
+        """Ticks recorded since the last ``clear``."""
+        return len(self._ticks)
+
+    def tick_records(self) -> List[Tuple[int, float, float, dict, dict]]:
+        """One record per recorded tick, in order: ``(tick, t0, t1,
+        phases, counts)`` with ``phases`` mapping each phase (and extra
+        span, e.g. ``draft``) to its ``(t0, t1)``, all on the timeline's
+        clock, and ``counts`` the tick's work as the engine reported
+        it."""
+        return list(self._ticks)
 
     def span(self, name: str, t0: float, t1: float,
              tid: int = _ENGINE_TID, **args) -> None:
@@ -260,7 +284,7 @@ class TickTimeline:
         self._spans.clear()
         self._instants.clear()
         self._counters.clear()
-        self.ticks = 0
+        self._ticks.clear()
 
     # -- export --------------------------------------------------------------
     def to_chrome(self) -> dict:
