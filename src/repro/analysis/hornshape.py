@@ -67,14 +67,18 @@ def _paged_entries() -> List[dict]:
         return args, {"scale": 0.5, **kw}
 
     def chunk(B, C, H, KH, D, psize, P, maxp, S_w=0, **kw):
+        kv = "int8" if kw.pop("quantized", False) else "bfloat16"
         args = [FakeArray((B, C, H, D)),
-                FakeArray((P, KH, psize, D), "bfloat16"),
-                FakeArray((P, KH, psize, D), "bfloat16"),
+                FakeArray((P, KH, psize, D), kv),
+                FakeArray((P, KH, psize, D), kv),
                 Table("block_tables", (B, maxp), 0, P - 1),
                 Table("starts", (B,), 0, maxp * psize),
                 Table("chunk_lens", (B,), 0, C)]
         if S_w:
             kw["logit_index"] = Table("logit_index", (B, S_w), 0, C - 1)
+        if kv == "int8":
+            kw["k_scale"] = FakeArray((P, KH))
+            kw["v_scale"] = FakeArray((P, KH))
         return args, {"scale": 0.5, **kw}
 
     return [
@@ -87,10 +91,24 @@ def _paged_entries() -> List[dict]:
          "build": lambda: decode(2, 4, 2, 8, 4, 6, 5, quantized=True,
                                  pages_per_step=2)},
         {"fn": "paged_chunk_attention", "label": "chunk/pps2-ragged",
-         "build": lambda: chunk(2, 3, 4, 2, 8, 4, 6, 5, pages_per_step=2)},
+         "build": lambda: chunk(2, 3, 4, 2, 8, 4, 6, 5, pages_per_step=2,
+                                heads_per_step=1)},
         {"fn": "paged_chunk_attention", "label": "chunk/verify-window",
          "build": lambda: chunk(2, 4, 4, 2, 8, 4, 6, 7, S_w=2,
-                                pages_per_step=3)},
+                                pages_per_step=3, heads_per_step=1)},
+        # one (1, KH, psize, D) block per page offset: all kv heads in a
+        # step, pages divisible and ragged, head groups, derived tiling
+        {"fn": "paged_chunk_attention", "label": "chunk/all-heads-pps4",
+         "build": lambda: chunk(2, 1, 8, 4, 8, 4, 12, 8, pages_per_step=4,
+                                heads_per_step=4)},
+        {"fn": "paged_chunk_attention", "label": "chunk/all-heads-ragged",
+         "build": lambda: chunk(2, 2, 8, 4, 8, 4, 12, 7, S_w=1,
+                                pages_per_step=3, heads_per_step=4)},
+        {"fn": "paged_chunk_attention", "label": "chunk/head-pairs-int8",
+         "build": lambda: chunk(2, 1, 8, 4, 8, 4, 12, 5, quantized=True,
+                                pages_per_step=2, heads_per_step=2)},
+        {"fn": "paged_chunk_attention", "label": "chunk/derived",
+         "build": lambda: chunk(3, 1, 4, 2, 8, 4, 20, 6)},
     ]
 
 
@@ -256,10 +274,13 @@ def check_kernels(root: Path = Path(".")) -> List[Tuple[str, Report]]:
 # --------------------------------------------------------------------------
 def crosscheck_paged_geometry(*, batch: int, kv_heads: int, head_dim: int,
                               page_size: int, num_pages: int,
-                              max_pages: int, pages_per_step: int = 1,
+                              max_pages: int,
+                              pages_per_step: Optional[int] = None,
                               quantized: bool = False) -> List[str]:
-    """Verify paged attention at a *concrete serving* geometry and compare
-    the symbolic verdicts against brute-force enumeration.  Returns alert
+    """Verify the serving step's paged chunk attention at a *concrete
+    serving* geometry — its decode call (chunk width 1), tiled as the
+    kernel tiles it (``pages_per_step=None``: derived) — and compare the
+    symbolic verdicts against brute-force enumeration.  Returns alert
     strings (empty == proved and consistent) for ``serve.py --sanitize``."""
     rel = "src/repro/kernels/paged_attention/kernel.py"
     path = _find_kernel_source(rel)
@@ -267,15 +288,16 @@ def crosscheck_paged_geometry(*, batch: int, kv_heads: int, head_dim: int,
         return [f"hornshape: cannot locate {rel}"]
     H = kv_heads * max(1, 4 // max(kv_heads, 1))  # any multiple of KH works
     entry = {
-        "fn": "paged_attention", "label": "runtime-geometry",
+        "fn": "paged_chunk_attention", "label": "runtime-geometry",
         "build": lambda: (
-            [FakeArray((batch, H, head_dim)),
+            [FakeArray((batch, 1, H, head_dim)),
              FakeArray((num_pages, kv_heads, page_size, head_dim),
                        "int8" if quantized else "bfloat16"),
              FakeArray((num_pages, kv_heads, page_size, head_dim),
                        "int8" if quantized else "bfloat16"),
              Table("block_tables", (batch, max_pages), 0, num_pages - 1),
-             Table("lengths", (batch,), 0, max_pages * page_size)],
+             Table("starts", (batch,), 0, max_pages * page_size),
+             Table("chunk_lens", (batch,), 0, 1)],
             dict(scale=1.0, pages_per_step=pages_per_step,
                  **({"k_scale": FakeArray((num_pages, kv_heads)),
                      "v_scale": FakeArray((num_pages, kv_heads))}

@@ -16,25 +16,33 @@ lives in a shared pool of fixed-size pages, so sequences of wildly different
 lengths pack the HBM densely and join/leave a batch without reshuffling).
 
 Pools are laid out [P, KH, psize, D], kv-heads ahead of the page's token
-axis, so each grid step reads a (1, 1, psize, D) block whose last two dims
-equal the pool's: the TPU compiler accepts any page size and head dim.
+axis, so one page of ``hps`` consecutive kv heads is one contiguous
+(1, hps, psize, D) block whose last two dims equal the pool's: the TPU
+compiler accepts any page size and head dim, and one DMA fetches it.
 
-Grid: (B, KH, ceil(maxp / pages_per_step)) — pages innermost (sequential).
-``dimension_semantics`` marks the (slot, kv-head) dimensions ``parallel`` so
-TPU megacore splits the work across cores; only the page axis stays
-``arbitrary`` (it carries the online-softmax (max, sum, acc) state in VMEM
-scratch).  The block table and the per-sequence lengths ride in as
-*scalar-prefetch* operands (``pltpu.PrefetchScalarGridSpec``) so the K/V
-``index_map`` can resolve ``block_tables[b, page]`` before the DMA is
-issued: the gather costs zero extra HBM traffic versus a contiguous cache.
+Grid: (B, KH / heads_per_step, ceil(maxp / pages_per_step)) — pages
+innermost (sequential).  ``dimension_semantics`` marks the (slot, head
+group) dimensions ``parallel`` so TPU megacore splits the work across
+cores; only the page axis stays ``arbitrary`` (it carries the
+online-softmax (max, sum, acc) state in VMEM scratch).  The block table and
+the per-sequence lengths ride in as *scalar-prefetch* operands
+(``pltpu.PrefetchScalarGridSpec``) so the K/V ``index_map`` can resolve
+``block_tables[b, page]`` before the DMA is issued: the gather costs zero
+extra HBM traffic versus a contiguous cache.
 
-``pages_per_step`` widens each grid step to ``pps`` whole pages: the grid's
-innermost extent collapses by that factor and every step carries ``pps``
-independently-indexed K and V blocks, so Pallas double-buffers the next
-step's page DMAs against the current step's compute (gathered pages are not
+A grid step carries ``pps`` whole pages of ``hps`` kv heads: one
+independently-indexed K and V block per page offset (gathered pages are not
 contiguous in the pool, hence one BlockSpec *per page offset* rather than
-one wider block).  ``pages_per_step=1`` reproduces the single-page kernel
-bit-for-bit.
+one wider block), so Pallas double-buffers the next step's page DMAs
+against the current step's compute.  Inside the step the online-softmax
+update runs page by page in page order and, within a page, head by head:
+every (page, head) update is the op sequence of the one-page, one-head
+kernel, so every tiling gives the same bits.  What a tiling changes is the
+number of grid steps and of DMAs and the bytes each DMA carries; on the
+chip one small DMA per page per kv head cost far more than the grid steps.
+``chunk_tiling`` derives the tiling from the call's shapes
+(``pages_per_step=None`` / ``heads_per_step=None``); explicit values are
+honoured.  The decode-only ``paged_attention`` keeps one head per step.
 
 Dead grid steps (pages past ``ceil(len / psize)``) are clamped to the null
 page 0 *in the index map* — stale or garbage block-table entries past a
@@ -54,8 +62,8 @@ window-compacted output — gathered in the kernel epilogue while the chunk
 output is still in VMEM, so speculative verify stops paying a separate
 device-wide gather pass over the full-width output.
 
-GQA: the grid iterates kv heads; each step processes the whole [G, D] group
-of query heads that share the kv head — no materialized K/V repeat.
+GQA: each (page, kv head) update processes the whole [G, D] group of query
+heads that share the kv head — no materialized K/V repeat.
 Sequences with ``length == 0`` (empty decode slots) emit zeros.
 """
 from __future__ import annotations
@@ -85,13 +93,48 @@ SCALE_ROWS = 8
 # the page axis is sequential (online-softmax carry in VMEM scratch)
 DIM_SEMANTICS = ("parallel", "parallel", "arbitrary")
 
+# chunk_tiling's limits, from sweeps on one v5e at the serving cell's
+# geometry (PERF.md).  What costs there is one DMA per page per kv head:
+# all kv heads in a step, one (1, KH, psize, D) block per page, ran the
+# C = 1 call 5.9x faster; pages per step moved it by 2-6%.
+# STEP_ROWS: query rows (heads x C*G) a step holds; their f32
+#   accumulators, lane-padded max / sum scratch and double-buffered q / out
+#   blocks live in VMEM (4096: C = 256 over all 8 heads, about 10 MiB).
+# STEP_UPDATES: (page, head) updates a step unrolls.  The kernel is traced
+#   once per chunk bucket: 64 of them (8 pages of 8 heads) made the serving
+#   cell's set-up 21-23 s longer, most of it tracing; a head loop rolled
+#   into a fori_loop kept the set-up but ran the kernel 3x slower.
+# STEP_ROW_PAGES: rows x pages, which bounds the unrolled work (C = 256
+#   over all heads ran as fast at 1 to 8 pages a step and at half speed
+#   at 16).
+STEP_ROWS = 4096
+STEP_UPDATES = 16
+STEP_ROW_PAGES = 8192
+
+
+def chunk_tiling(C: int, G: int, KH: int, maxp: int):
+    """(pages_per_step, heads_per_step) of ``paged_chunk_attention`` for a
+    C-token chunk of G query heads per kv head over a pool of KH kv heads
+    and tables maxp pages wide.  All kv heads share a step while their
+    C * G query rows stay within ``STEP_ROWS`` (else the widest divisor of
+    KH that does); the step then takes as many pages as keep its updates
+    within ``STEP_UPDATES`` and its rows x pages within
+    ``STEP_ROW_PAGES``.  A function of shapes alone: every value gives the
+    same bits."""
+    hps = KH
+    while hps > 1 and (hps * C * G > STEP_ROWS or KH % hps):
+        hps -= 1
+    pps = min(maxp, STEP_UPDATES // hps, STEP_ROW_PAGES // (hps * C * G))
+    return max(1, pps), hps
+
 
 def _kv_page_specs(*, pps: int, psize: int, maxp: int, D: int, KH: int,
-                   length_of, quantized: bool):
-    """One (1, 1, psize, D) K/V BlockSpec per page offset j of a grid step
-    over the [P, KH, psize, D] pool, plus one (SCALE_ROWS, KH) scale spec per
-    offset in int8 mode (the row group of the [P, KH] scale array that holds
-    the page; ``_page_scale`` picks the (page, head) entry in-register).
+                   length_of, quantized: bool, hps: int = 1):
+    """One (1, hps, psize, D) K/V BlockSpec per page offset j of a grid step
+    over the [P, KH, psize, D] pool (head group h: kv heads h*hps ..
+    h*hps + hps - 1), plus one (SCALE_ROWS, KH) scale spec per offset in
+    int8 mode (the row group of the [P, KH] scale array that holds the
+    page; ``_page_scale`` picks the (page, head) entry in-register).
     Dead pages (past the sequence's live length) are clamped to the null page
     0 in the index map itself, so garbage block-table entries are never
     dereferenced and no DMA bandwidth is spent on them."""
@@ -107,7 +150,7 @@ def _kv_page_specs(*, pps: int, psize: int, maxp: int, D: int, KH: int,
     def sc_map(j):
         return lambda b, h, p, *refs: (page_of(b, p, j, refs) // SCALE_ROWS, 0)
 
-    kv = [pl.BlockSpec((1, 1, psize, D), kv_map(j)) for j in range(pps)]
+    kv = [pl.BlockSpec((1, hps, psize, D), kv_map(j)) for j in range(pps)]
     sc = [pl.BlockSpec((SCALE_ROWS, KH), sc_map(j)) for j in range(pps)] \
         if quantized else []
     return kv, sc
@@ -190,14 +233,16 @@ def _kernel(bt_ref, len_ref, q_ref, *rest, scale: float,
 
 def _chunk_kernel(bt_ref, start_ref, clen_ref, *rest, scale: float,
                   window: Optional[int], softcap: Optional[float],
-                  psize: int, grid_p: int, pps: int, C: int, G: int,
-                  quantized: bool, S_w: int):
-    """Chunk-append variant: q is [C * G, D] per (sequence, kv-head) — C
-    chunk tokens x G grouped query heads.  Row r holds chunk token r // G at
-    absolute position ``start + r // G``; the mask adds a causal constraint
-    against the token's own chunk prefix on top of the decode kernel's
-    length mask.  Padding rows (token index >= chunk_len) are zeroed at
-    emit.  With C == 1 every op matches ``_kernel`` bit-for-bit.
+                  psize: int, grid_p: int, pps: int, hps: int, C: int,
+                  G: int, quantized: bool, S_w: int):
+    """Chunk-append variant: q is [hps, C * G, D] per (sequence, head
+    group) — per kv head, C chunk tokens x G grouped query heads.  Row r
+    holds chunk token r // G at absolute position ``start + r // G``; the
+    mask adds a causal constraint against the token's own chunk prefix on
+    top of the decode kernel's length mask.  Padding rows (token index >=
+    chunk_len) are zeroed at emit.  Each (page, head) update is the op
+    sequence of the one-page, one-head step, so every (pps, hps) gives the
+    same bits, and with C == 1 every op matches ``_kernel`` bit-for-bit.
 
     ``S_w > 0``: a ``logit_index`` [B, S_w] scalar-prefetch operand follows
     the block table, and the epilogue additionally writes the S_w selected
@@ -212,7 +257,7 @@ def _chunk_kernel(bt_ref, start_ref, clen_ref, *rest, scale: float,
         o_ref, ow_ref, acc_ref, m_ref, l_ref = tail
     else:
         o_ref, acc_ref, m_ref, l_ref = tail
-    b, h, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    b, hg, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(p == 0)
     def _init():
@@ -229,48 +274,54 @@ def _chunk_kernel(bt_ref, start_ref, clen_ref, *rest, scale: float,
 
         @pl.when(live)
         def _page(j=j, pg=pg):
-            q = q_ref[0, 0].astype(f32)                 # [C * G, D]
-            k = k_refs[j][0, 0].astype(f32)             # [psize, D]
-            v = v_refs[j][0, 0].astype(f32)
-            if quantized:                               # in-register dequant
-                page = bt_ref[b, pg]
-                k = k * _page_scale(ks_refs[j], page, h)
-                v = v * _page_scale(vs_refs[j], page, h)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=f32) * scale
-            if softcap:
-                s = jnp.tanh(s / softcap) * softcap
+            shape = (C * G, psize)
             kpos = pg * psize + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)                  # [C*G, psize]
+                jnp.int32, shape, 1)                    # [C*G, psize]
             qpos = start + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0) // G             # row r -> token r // G
+                jnp.int32, shape, 0) // G               # row r -> token r // G
             mask = jnp.where(kpos >= length, NEG_INF, 0.0)
             mask = jnp.where(kpos > qpos, NEG_INF, mask)   # causal own-chunk
             if window is not None:
                 mask = jnp.where(kpos <= qpos - window, NEG_INF, mask)
-            s = s + mask
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            prob = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_ref[...] * corr + jnp.sum(prob, -1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-                prob, v, (((1,), (0,)), ((), ())), preferred_element_type=f32)
-            m_ref[...] = m_new
+            for i in range(hps):
+                q = q_ref[0, i].astype(f32)             # [C * G, D]
+                k = k_refs[j][0, i].astype(f32)         # [psize, D]
+                v = v_refs[j][0, i].astype(f32)
+                if quantized:                           # in-register dequant
+                    page = bt_ref[b, pg]
+                    k = k * _page_scale(ks_refs[j], page, hg * hps + i)
+                    v = v * _page_scale(vs_refs[j], page, hg * hps + i)
+                s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=f32) * scale
+                if softcap:
+                    s = jnp.tanh(s / softcap) * softcap
+                s = s + mask
+                m_prev = m_ref[i]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                prob = jnp.exp(s - m_new)
+                corr = jnp.exp(m_prev - m_new)
+                l_ref[i] = l_ref[i] * corr + jnp.sum(prob, -1, keepdims=True)
+                acc_ref[i] = acc_ref[i] * corr + jax.lax.dot_general(
+                    prob, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=f32)
+                m_ref[i] = m_new
 
     @pl.when(p == grid_p - 1)
     def _emit():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        tok = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0) // G
-        final = jnp.where(tok < clen, out, 0.0).astype(o_ref.dtype)
-        o_ref[0, 0] = final
-        if S_w:
-            # fused verify window: gather the S_w selected rows while the
-            # chunk output sits in VMEM (row tok t -> q-head group t*G:+G)
-            for sw in range(S_w):
-                t = widx_ref[b, sw]
-                ow_ref[0, 0, sw * G:(sw + 1) * G, :] = \
-                    jax.lax.dynamic_slice_in_dim(final, t * G, G, axis=0)
+        for i in range(hps):
+            out = acc_ref[i] / jnp.maximum(l_ref[i], 1e-30)
+            tok = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0) // G
+            final = jnp.where(tok < clen, out, 0.0).astype(o_ref.dtype)
+            o_ref[0, i] = final
+            if S_w:
+                # fused verify window: gather the S_w selected rows while
+                # the chunk output sits in VMEM (row tok t -> q-head group
+                # t*G:+G)
+                for sw in range(S_w):
+                    t = widx_ref[b, sw]
+                    ow_ref[0, i, sw * G:(sw + 1) * G, :] = \
+                        jax.lax.dynamic_slice_in_dim(final, t * G, G, axis=0)
 
 
 def _check_quant(k_pages, k_scale, v_scale):
@@ -283,21 +334,24 @@ def _check_quant(k_pages, k_scale, v_scale):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "window", "softcap", "interpret", "pages_per_step"))
+    "scale", "window", "softcap", "interpret", "pages_per_step",
+    "heads_per_step"))
 def paged_chunk_attention(q, k_pages, v_pages, block_tables, starts,
                           chunk_lens, *, scale: float,
                           window: Optional[int] = None,
                           softcap: Optional[float] = None,
                           interpret: bool = False,
-                          pages_per_step: int = 1,
+                          pages_per_step: Optional[int] = None,
+                          heads_per_step: Optional[int] = None,
                           k_scale=None, v_scale=None, logit_index=None):
     """q: [B, C, H, D] right-padded chunks; k/v_pages: [P, KH, psize, D]
     (the chunk's own K/V already appended); block_tables: [B, maxp];
     starts/chunk_lens: [B] -> [B, C, H, D].  See paged_chunk_attention_ref
     for the contract; C == 1 reproduces ``paged_attention`` bit-for-bit.
 
-    ``pages_per_step`` processes that many pages per grid step (double-
-    buffered page DMAs); 1 reproduces the single-page kernel bit-for-bit.
+    ``pages_per_step`` pages of ``heads_per_step`` kv heads make one grid
+    step; ``None`` takes ``chunk_tiling``'s value.  Every tiling gives the
+    same bits.
     ``k_scale``/``v_scale`` ([P, KH] f32) enable the int8-pool mode.
     ``logit_index`` ([B, S] int32 chunk positions, each < chunk_len or 0)
     switches the return to ``(out [B, C, H, D], out_win [B, S, H, D])``
@@ -307,7 +361,14 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, starts,
     maxp = block_tables.shape[1]
     G = H // KH
     quantized = _check_quant(k_pages, k_scale, v_scale)
-    pps = max(1, min(pages_per_step, maxp))
+    pps, hps = chunk_tiling(C, G, KH, maxp)
+    if pages_per_step is not None:
+        pps = max(1, min(pages_per_step, maxp))
+    if heads_per_step is not None:
+        hps = heads_per_step
+    if hps < 1 or KH % hps:
+        raise ValueError(f"heads_per_step={hps} must divide the {KH} kv "
+                         f"heads")
     grid_p = -(-maxp // pps)
     S_w = 0 if logit_index is None else logit_index.shape[1]
     # [B, KH, C*G, D]: chunk tokens x grouped query heads, flattened so the
@@ -317,30 +378,32 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, starts,
 
     kernel = functools.partial(
         _chunk_kernel, scale=scale, window=window, softcap=softcap,
-        psize=psize, grid_p=grid_p, pps=pps, C=C, G=G, quantized=quantized,
-        S_w=S_w)
+        psize=psize, grid_p=grid_p, pps=pps, hps=hps, C=C, G=G,
+        quantized=quantized, S_w=S_w)
     kv_specs, sc_specs = _kv_page_specs(
         pps=pps, psize=psize, maxp=maxp, D=D, KH=KH,
-        length_of=lambda b, refs: refs[1][b] + refs[2][b], quantized=quantized)
-    q_spec = pl.BlockSpec((1, 1, C * G, D), lambda b, h, p, *refs: (b, h, 0, 0))
-    out_spec = pl.BlockSpec((1, 1, C * G, D),
+        length_of=lambda b, refs: refs[1][b] + refs[2][b], quantized=quantized,
+        hps=hps)
+    q_spec = pl.BlockSpec((1, hps, C * G, D),
+                          lambda b, h, p, *refs: (b, h, 0, 0))
+    out_spec = pl.BlockSpec((1, hps, C * G, D),
                             lambda b, h, p, *refs: (b, h, 0, 0))
     out_shape = jax.ShapeDtypeStruct((B, KH, C * G, D), q.dtype)
     out_specs, out_shapes = out_spec, out_shape
     if S_w:
         out_specs = [out_spec,
-                     pl.BlockSpec((1, 1, S_w * G, D),
+                     pl.BlockSpec((1, hps, S_w * G, D),
                                   lambda b, h, p, *refs: (b, h, 0, 0))]
         out_shapes = [out_shape,
                       jax.ShapeDtypeStruct((B, KH, S_w * G, D), q.dtype)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 + (1 if S_w else 0),
-        grid=(B, KH, grid_p),
+        grid=(B, KH // hps, grid_p),
         in_specs=[q_spec] + kv_specs + kv_specs + sc_specs + sc_specs,
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((C * G, D), f32),
-                        pltpu.VMEM((C * G, 1), f32),
-                        pltpu.VMEM((C * G, 1), f32)],
+        scratch_shapes=[pltpu.VMEM((hps, C * G, D), f32),
+                        pltpu.VMEM((hps, C * G, 1), f32),
+                        pltpu.VMEM((hps, C * G, 1), f32)],
     )
     scalars = [block_tables.astype(jnp.int32), starts.astype(jnp.int32),
                chunk_lens.astype(jnp.int32)]

@@ -21,21 +21,23 @@ f32 = jnp.float32
 # Default pages-per-grid-step for the Pallas kernels (the engine sets this
 # once at construction from EngineConfig.pages_per_step, before tracing its
 # jitted steps; kernel-level callers can always pass pages_per_step=...
-# explicitly).  1 reproduces the classic single-page kernel bit-for-bit.
-_PAGES_PER_STEP = 1
+# explicitly).  None: the chunk kernel derives its tiling from the call's
+# shapes (``kernel.chunk_tiling``), the decode kernel takes one page.
+_PAGES_PER_STEP: Optional[int] = None
 
 
-def set_pages_per_step(n: int) -> None:
+def set_pages_per_step(n: Optional[int]) -> None:
     """Set the process-wide default ``pages_per_step`` for the paged
-    kernels.  A static tuning knob: it is read at trace time, so set it
-    before the first call of any jitted step that should use it."""
+    kernels (None: derived from shapes).  A static tuning knob: it is read
+    at trace time, so set it before the first call of any jitted step that
+    should use it."""
     global _PAGES_PER_STEP
-    if n < 1:
+    if n is not None and n < 1:
         raise ValueError(f"pages_per_step must be >= 1, got {n}")
-    _PAGES_PER_STEP = int(n)
+    _PAGES_PER_STEP = None if n is None else int(n)
 
 
-def get_pages_per_step() -> int:
+def get_pages_per_step() -> Optional[int]:
     return _PAGES_PER_STEP
 
 
@@ -50,7 +52,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         return paged_attention_ref(q, k_pages, v_pages, block_tables, lengths,
                                    scale=scale, window=window, softcap=softcap,
                                    k_scale=k_scale, v_scale=v_scale)
-    kw.setdefault("pages_per_step", _PAGES_PER_STEP)
+    if _PAGES_PER_STEP is not None:
+        kw.setdefault("pages_per_step", _PAGES_PER_STEP)
     return _pallas(q, k_pages, v_pages, block_tables, lengths, scale=scale,
                    window=window, softcap=softcap, k_scale=k_scale,
                    v_scale=v_scale, interpret=backend == "interpret", **kw)

@@ -45,7 +45,8 @@ inside the kernels): ~2x sequences at equal HBM and fewer pool-pressure
 preemptions, with a bounded greedy-decode divergence instead of the f32
 path's byte-identity.  ``--pages-per-step N`` makes the paged kernels
 fetch N KV pages per grid step (double-buffered page DMAs on TPU) —
-bit-identical output for any N.
+bit-identical output for any N; by default the chunk kernel derives its
+tiling from the call's shapes.
 
 Observability (``repro.serving.observability``): ``--stats-every N``
 prints a periodic stats line off the engine's telemetry snapshot;
@@ -174,10 +175,10 @@ def make_parser() -> argparse.ArgumentParser:
                          "plus per-(page, kv-head) f32 scale sidecars: "
                          "~2x sequences at equal HBM, bounded-error decode "
                          "(dequantized in-register inside the kernel)")
-    ap.add_argument("--pages-per-step", type=int, default=1,
+    ap.add_argument("--pages-per-step", type=int, default=None,
                     help="KV pages fetched per paged-attention grid step "
-                         "(>1 double-buffers page DMAs for more HBM "
-                         "bandwidth; output is bit-identical for any value)")
+                         "(default: derived from the call's shapes; output "
+                         "is bit-identical for any value)")
     ap.add_argument("--speculate", type=int, default=0, metavar="K",
                     help="speculative decoding: a materialized draft "
                          "circuit proposes K tokens per decode tick, the "

@@ -133,8 +133,9 @@ class EngineConfig:
                                      # sidecars — ~2x pages at equal HBM,
                                      # bounded-error decode)
     compute_dtype: str = "bfloat16"  # model compute dtype
-    pages_per_step: int = 1          # KV pages per paged-kernel grid step
-                                     # (>1 double-buffers page DMAs; output
+    pages_per_step: Optional[int] = None  # KV pages per paged-kernel grid
+                                     # step (None: derived from the call's
+                                     # shapes, kernel.chunk_tiling; output
                                      # is bit-identical across values)
     prefix_cache: bool = True        # content-addressed page reuse + COW
                                      # (off: PR-3-style per-request prefill)
@@ -276,6 +277,9 @@ class Engine:
         self.obs.set_engine_config(
             kv_dtype=ecfg.kv_dtype, compute_dtype=ecfg.compute_dtype,
             pages_per_step=ecfg.pages_per_step,
+            attn_tiling={str(1 << i): list(self._attn_tiling(1 << i))
+                         for i in range(pow2_bucket(self.max_chunk)
+                                        .bit_length())},
             speculate_k=ecfg.speculate_k,
             bank_size=bank.num_submodels if bank is not None else 0,
             num_slots=ecfg.num_slots, num_pages=ecfg.num_pages,
@@ -845,19 +849,41 @@ class Engine:
                          live_pages=self.pool.live_table_pages)
         return done + finished
 
+    def _attn_tiling(self, C: int):
+        """(pages_per_step, heads_per_step) of the paged-attention call at
+        chunk width ``C``: ``EngineConfig.pages_per_step`` where set, else
+        what ``chunk_tiling`` derives, as the kernel does."""
+        from repro.kernels.paged_attention.kernel import chunk_tiling
+        KH, maxp = self.cfg.num_kv_heads, self.max_pages_per_seq
+        pps, hps = chunk_tiling(C, self.cfg.num_heads // KH, KH, maxp)
+        if self.ecfg.pages_per_step is not None:
+            pps = max(1, min(self.ecfg.pages_per_step, maxp))
+        return pps, hps
+
     def _tick_counts(self, entries: Dict[int, _Entry], C: int) -> dict:
         """The work of one device tick: ``real_tokens`` the slots' chunk
         lengths, ``prefill_tokens`` those of slots still in prefill,
         ``padded_tokens`` the positions the step computes (every slot
         padded to the chunk width ``chunk``), ``live_slots`` the slots
-        that carry a chunk."""
+        that carry a chunk; ``attn_grid_steps`` the grid steps of one
+        layer's paged-attention call, ``attn_live_steps`` those that hold
+        at least one live page (their ratio is the dead-step share)."""
+        pps, hps = self._attn_tiling(C)
+        groups = self.cfg.num_kv_heads // hps
+        psize = self.ecfg.page_size
+        live_pages = [-(-(e.start + e.chunk_len) // psize)
+                      for e in entries.values()]
+        live = sum(-(-n // pps) for n in live_pages)
         return {"tick": self.steps,
                 "real_tokens": sum(e.chunk_len for e in entries.values()),
                 "prefill_tokens": sum(e.chunk_len for e in entries.values()
                                       if e.req.in_prefill),
                 "padded_tokens": self.ecfg.num_slots * C,
                 "chunk": C,
-                "live_slots": len(entries)}
+                "live_slots": len(entries),
+                "attn_grid_steps": self.ecfg.num_slots * groups
+                * -(-self.max_pages_per_seq // pps),
+                "attn_live_steps": live * groups}
 
     def _commit_spec(self, slot: int, e: _Entry, sampled: int, acc: int,
                      now: float) -> None:

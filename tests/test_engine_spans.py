@@ -37,12 +37,13 @@ def _engine(params, draft=None, **over):
 
 
 def _record_calls(engine):
-    """Wrap the engine's step: (C, chunk_lens) of every device call."""
+    """Wrap the engine's step: (C, chunk_lens, starts) of every device
+    call."""
     calls = []
     step = engine._step
 
     def recorded(*a, **kw):
-        calls.append((a[2].shape[1], np.asarray(a[4])))
+        calls.append((a[2].shape[1], np.asarray(a[4]), np.asarray(a[3])))
         return step(*a, **kw)
 
     engine._step = recorded
@@ -80,6 +81,25 @@ def _children(events, parent, names):
             if ev[0] in names and s0 <= ev[1] and ev[2] <= e0]
 
 
+def _attn_steps(engine, C, starts, lens):
+    """The grid steps of one layer's paged-attention call at chunk width
+    C, recomputed from the call's starts and chunk lengths: all of them,
+    and those whose page group holds a live page (pages before
+    ``start + chunk_len``)."""
+    from repro.kernels.paged_attention.kernel import chunk_tiling
+    KH, psize = CFG.num_kv_heads, engine.ecfg.page_size
+    maxp = engine._bt.dev.shape[1]
+    pps, hps = chunk_tiling(C, CFG.num_heads // KH, KH, maxp)
+    if engine.ecfg.pages_per_step is not None:
+        pps = engine.ecfg.pages_per_step
+    live = 0
+    for n in starts + lens:
+        for g in range(-(-maxp // pps)):
+            live += g * pps * psize < n
+    return {"attn_grid_steps": len(starts) * (KH // hps) * -(-maxp // pps),
+            "attn_live_steps": live * (KH // hps)}
+
+
 def _prompts(lens, seed=1):
     rng = np.random.default_rng(seed)
     return [rng.integers(1, CFG.vocab_size, (n,)).astype(np.int32)
@@ -104,12 +124,13 @@ def test_every_device_tick_has_one_span_with_its_counts(params, tmp_path):
     assert len(ticks) == engine.steps == len(calls) > 0
     B = engine.ecfg.num_slots
     mixed = 0
-    for i, (tick, (C, lens)) in enumerate(zip(ticks, calls)):
+    for i, (tick, (C, lens, starts)) in enumerate(zip(ticks, calls)):
         st = tick[3]
         assert st == {"tick": i, "real_tokens": int(lens.sum()),
                       "prefill_tokens": st["prefill_tokens"],
                       "padded_tokens": B * C, "chunk": C,
-                      "live_slots": int((lens > 0).sum())}
+                      "live_slots": int((lens > 0).sum()),
+                      **_attn_steps(engine, C, starts, lens)}
         assert 0 <= st["prefill_tokens"] <= st["real_tokens"]
         if 0 < st["prefill_tokens"] < st["real_tokens"]:
             mixed += 1
@@ -137,14 +158,36 @@ def test_every_device_tick_has_one_span_with_its_counts(params, tmp_path):
     # and the engine's cumulative padding counters their sums
     snap = engine.metrics()
     m = snap["counters"]
-    assert m["real_tokens"] == sum(int(l.sum()) for _, l in calls)
-    assert m["padded_tokens"] == sum(B * C for C, _ in calls)
+    assert m["real_tokens"] == sum(int(l.sum()) for _, l, _ in calls)
+    assert m["padded_tokens"] == sum(B * C for C, _, _ in calls)
     assert snap["tick"]["tokens_per_tick"]["count"] == len(calls)
     assert engine.obs.tokens_per_tick.max == max(int(l.sum())
-                                                 for _, l in calls)
+                                                 for _, l, _ in calls)
     engine.reset_stats()
     assert engine.real_tokens == engine.padded_tokens == 0
     assert engine.obs.timeline.tick_records() == []
+
+
+@pytest.mark.parametrize("pages_per_step", [None, 3])
+def test_tick_counts_carry_the_attention_grid_steps(params, pages_per_step):
+    """Every tick record's ``attn_grid_steps`` / ``attn_live_steps`` equal
+    the paged call's grid steps and live steps recomputed from that tick's
+    starts and chunk lengths, under the derived tiling and an explicit
+    ``pages_per_step``; short contexts in 8-page tables leave dead steps."""
+    engine = _engine(params, pages_per_step=pages_per_step)
+    calls = _record_calls(engine)
+    for p in _prompts((9, 6, 21)):
+        engine.submit(p, 6)
+    engine.run()
+    recs = engine.obs.timeline.tick_records()
+    assert len(recs) == len(calls) > 0
+    dead = 0
+    for (_, _, _, _, counts), (C, lens, starts) in zip(recs, calls):
+        want = _attn_steps(engine, C, starts, lens)
+        assert {k: counts[k] for k in want} == want
+        assert 0 < counts["attn_live_steps"] <= counts["attn_grid_steps"]
+        dead += counts["attn_grid_steps"] - counts["attn_live_steps"]
+    assert dead > 0
 
 
 def test_speculative_tick_nests_its_draft_span(params, tmp_path):

@@ -646,6 +646,33 @@ def _chunk_case(B, H, KH, D, maxp, C, seed, *, int8=False):
             jnp.asarray(clens)), scales
 
 
+_SWEEP_BASE = {}
+
+
+def _sweep_case(variant, C, dtype):
+    """(args, kwargs, one-page one-head output) of a sweep-matrix case,
+    the output checked against the pure-jnp ref once per case."""
+    key = (variant, C, dtype)
+    if key not in _SWEEP_BASE:
+        vid = {"plain": 1, "window": 2, "softcap": 3, "gqa": 4}[variant]
+        H, KH = (4, 2) if variant == "gqa" else (4, 4)
+        D, maxp = 16, 4
+        args, scales = _chunk_case(2, H, KH, D, maxp, C, (vid, C),
+                                   int8=dtype == "int8")
+        kw = dict(scales, scale=D ** -0.5)
+        if variant == "window":
+            kw["window"] = PSIZE + 3
+        elif variant == "softcap":
+            kw["softcap"] = 30.0
+        ref = paged_chunk_attention_ref(*args, **kw)
+        base = paged_chunk_attention(*args, interpret=True, pages_per_step=1,
+                                     heads_per_step=1, **kw)
+        np.testing.assert_allclose(np.asarray(base), np.asarray(ref),
+                                   atol=2e-5, rtol=1e-5)
+        _SWEEP_BASE[key] = args, kw, np.asarray(base)
+    return _SWEEP_BASE[key]
+
+
 @pytest.mark.parametrize("variant", ["plain", "window", "softcap", "gqa"])
 @pytest.mark.parametrize("C", [1, 4])
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
@@ -654,26 +681,95 @@ def test_paged_chunk_pages_per_step_sweep(variant, C, dtype):
     dtype) must be allclose to the pure-jnp ref, and pages_per_step in
     {2, 4} must be *bit-for-bit* identical to pages_per_step=1 (the grid
     restructure only changes DMA scheduling, never the op sequence)."""
-    vid = {"plain": 1, "window": 2, "softcap": 3, "gqa": 4}[variant]
-    H, KH = (4, 2) if variant == "gqa" else (4, 4)
-    D, maxp = 16, 4
-    args, scales = _chunk_case(2, H, KH, D, maxp, C, (vid, C),
-                               int8=dtype == "int8")
-    kw = dict(scales)
-    if variant == "window":
-        kw["window"] = PSIZE + 3
-    elif variant == "softcap":
-        kw["softcap"] = 30.0
-    ref = paged_chunk_attention_ref(*args, scale=D ** -0.5, **kw)
-    base = paged_chunk_attention(*args, scale=D ** -0.5, interpret=True,
-                                 pages_per_step=1, **kw)
-    np.testing.assert_allclose(np.asarray(base), np.asarray(ref),
-                               atol=2e-5, rtol=1e-5)
+    args, kw, base = _sweep_case(variant, C, dtype)
     for pps in (2, 4):
-        out = paged_chunk_attention(*args, scale=D ** -0.5, interpret=True,
-                                    pages_per_step=pps, **kw)
-        assert np.array_equal(np.asarray(out), np.asarray(base)), \
+        out = paged_chunk_attention(*args, interpret=True, pages_per_step=pps,
+                                    heads_per_step=1, **kw)
+        assert np.array_equal(np.asarray(out), base), \
             f"pages_per_step={pps} changed bits ({variant}, C={C}, {dtype})"
+
+
+@pytest.mark.parametrize("tiling", ["all-heads", "derived"])
+@pytest.mark.parametrize("variant", ["plain", "window", "softcap", "gqa"])
+@pytest.mark.parametrize("C", [1, 4])
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_paged_chunk_tiling_sweep(tiling, variant, C, dtype):
+    """The same matrix with every kv head in one grid step (pages_per_step
+    1 and a ragged 3 over 4-page tables) and with the tiling
+    ``chunk_tiling`` derives: bit-for-bit the one-page, one-head kernel."""
+    args, kw, base = _sweep_case(variant, C, dtype)
+    KH = args[1].shape[1]
+    tilings = [(1, KH), (3, KH)] if tiling == "all-heads" else [(None, None)]
+    for pps, hps in tilings:
+        out = paged_chunk_attention(*args, interpret=True, pages_per_step=pps,
+                                    heads_per_step=hps, **kw)
+        assert np.array_equal(np.asarray(out), base), \
+            f"tiling ({pps}, {hps}) changed bits ({variant}, C={C}, {dtype})"
+
+
+def test_chunk_tiling_at_the_serving_cell():
+    """The tiling the serving benchmark's geometry derives (qwen3-1.7b: G 2,
+    8 kv heads; 128-page tables): decode and prompt chunks up to C = 256
+    take 2 pages of all 8 kv heads a step; only wider chunks split the
+    heads, and a 1-page table takes 1 page."""
+    from repro.kernels.paged_attention.kernel import chunk_tiling
+
+    assert chunk_tiling(1, 2, 8, 128) == (2, 8)
+    assert chunk_tiling(8, 2, 8, 128) == (2, 8)
+    assert chunk_tiling(256, 2, 8, 128) == (2, 8)
+    assert chunk_tiling(512, 2, 8, 128) == (2, 4)
+    assert chunk_tiling(4096, 2, 8, 128) == (1, 1)
+    assert chunk_tiling(1, 2, 8, 1) == (1, 8)
+    assert chunk_tiling(1, 1, 4, 128) == (4, 4)
+
+
+def _edge_case(case):
+    """Chunk-attention inputs (B 3, KH 4, tables 5 pages wide) whose live
+    lengths hit a tiling edge: ``ragged`` random lengths over a table
+    width that 2 and 3 do not divide, ``empty-slot`` a slot with no
+    tokens at all, ``group-boundary`` live pages that end exactly where a
+    2- or 4-page group ends (and one token past it)."""
+    B, H, KH, D, maxp, C = 3, 8, 4, 16, 5, 2
+    rng = np.random.default_rng({"ragged": 41, "empty-slot": 42,
+                                 "group-boundary": 43}[case])
+    starts = {"ragged": rng.integers(0, maxp * PSIZE - C + 1, B),
+              "empty-slot": np.asarray([9, 0, 13]),
+              "group-boundary": np.asarray([2 * PSIZE - C, 4 * PSIZE - C,
+                                            4 * PSIZE - C + 1])}[case]
+    clens = np.asarray([0, C, C] if case == "empty-slot"
+                       else [C, 1, C - 1] if case == "ragged" else [C] * B)
+    P = B * maxp + 1
+    bt = np.zeros((B, maxp), np.int32)
+    for b in range(B):
+        npg = -(-(int(starts[b]) + int(clens[b])) // PSIZE)
+        bt[b, :npg] = 1 + b * maxp + np.arange(npg)
+    args = (jnp.asarray(rng.normal(size=(B, C, H, D)), jnp.float32),
+            jnp.asarray(rng.normal(size=(P, KH, PSIZE, D)), jnp.float32),
+            jnp.asarray(rng.normal(size=(P, KH, PSIZE, D)), jnp.float32),
+            jnp.asarray(bt), jnp.asarray(starts, jnp.int32),
+            jnp.asarray(clens, jnp.int32))
+    return args, D ** -0.5
+
+
+@pytest.mark.parametrize("case", ["ragged", "empty-slot", "group-boundary"])
+def test_paged_chunk_tiling_edges(case):
+    """Tables whose width the page group does not divide, a slot of
+    length 0 (zeros out, its neighbours untouched) and live pages ending
+    on a group boundary: every tiling — per head or all heads, 1 to 4
+    pages, derived — bit-for-bit the one-page, one-head kernel, which
+    matches the ref."""
+    args, scale = _edge_case(case)
+    base = np.asarray(paged_chunk_attention(
+        *args, scale=scale, interpret=True, pages_per_step=1,
+        heads_per_step=1))
+    ref = paged_chunk_attention_ref(*args, scale=scale)
+    np.testing.assert_allclose(base, np.asarray(ref), atol=2e-5, rtol=1e-5)
+    if case == "empty-slot":
+        assert not base[0].any() and base[1].any()
+    for pps, hps in ((2, 1), (3, 1), (2, 4), (3, 2), (4, 4), (None, None)):
+        out = paged_chunk_attention(*args, scale=scale, interpret=True,
+                                    pages_per_step=pps, heads_per_step=hps)
+        assert np.array_equal(np.asarray(out), base), (case, pps, hps)
 
 
 def test_paged_decode_pages_per_step_bitwise():
@@ -698,12 +794,10 @@ def test_paged_decode_pages_per_step_bitwise():
         assert np.array_equal(np.asarray(out), np.asarray(base)), pps
 
 
-def test_dead_block_table_entries_never_gathered():
-    """Block-table rows past a sequence's live length may hold stale or
-    out-of-range page ids (freed pages, preemption leftovers) — the kernels
-    must clamp their gathers to the null page, never dereference them.
-    Poisoning every dead entry with an id far outside the pool must leave
-    the output bit-for-bit unchanged."""
+def _poisoned_tables_case(tilings):
+    """Chunk attention over a clean block table and over the same table
+    with every dead entry poisoned: bit-for-bit equal at each (pps, hps)
+    of ``tilings``."""
     B, H, KH, D, maxp, C = 2, 4, 2, 16, 4, 4
     args, _ = _chunk_case(B, H, KH, D, maxp, C, 23)
     q, kp, vp, bt, starts, clens = args
@@ -713,14 +807,25 @@ def test_dead_block_table_entries_never_gathered():
     for b in range(B):
         poisoned[b, max(1, live[b]):] = 999_999      # way out of range
     assert not np.array_equal(poisoned, bt_np)
-    for pps in (1, 2):
+    for pps, hps in tilings:
+        kw = dict(scale=D ** -0.5, interpret=True, pages_per_step=pps,
+                  heads_per_step=hps)
         clean = paged_chunk_attention(q, kp, vp, jnp.asarray(bt_np), starts,
-                                      clens, scale=D ** -0.5, interpret=True,
-                                      pages_per_step=pps)
+                                      clens, **kw)
         dirty = paged_chunk_attention(q, kp, vp, jnp.asarray(poisoned),
-                                      starts, clens, scale=D ** -0.5,
-                                      interpret=True, pages_per_step=pps)
+                                      starts, clens, **kw)
         assert np.array_equal(np.asarray(clean), np.asarray(dirty)), pps
+    return q, kp, vp, bt_np, poisoned, starts, clens, D
+
+
+def test_dead_block_table_entries_never_gathered():
+    """Block-table rows past a sequence's live length may hold stale or
+    out-of-range page ids (freed pages, preemption leftovers) — the kernels
+    must clamp their gathers to the null page, never dereference them.
+    Poisoning every dead entry with an id far outside the pool must leave
+    the output bit-for-bit unchanged."""
+    q, kp, vp, bt_np, poisoned, starts, clens, D = _poisoned_tables_case(
+        [(1, 1), (2, 1)])
     # decode kernel too
     lengths = jnp.asarray(np.asarray(starts) + np.asarray(clens), jnp.int32)
     dec_c = paged_attention(q[:, 0], kp, vp, jnp.asarray(bt_np), lengths,
@@ -730,29 +835,53 @@ def test_dead_block_table_entries_never_gathered():
     assert np.array_equal(np.asarray(dec_c), np.asarray(dec_d))
 
 
-def test_fused_verify_window_matches_post_gather():
-    """logit_index mode: the kernel's fused window output must equal
-    gathering the same rows from the full-width output — bitwise — and the
-    full-width output itself must be unchanged by the extra operand."""
+@pytest.mark.parametrize("tiling", [(2, 2), (3, 2), (None, None)])
+def test_dead_block_table_entries_never_gathered_all_heads(tiling):
+    """The poisoned-table invariant with every kv head in one step: the
+    (1, KH, psize, D) blocks' null-page clamp holds for whole and ragged
+    page groups and for the derived tiling."""
+    _poisoned_tables_case([tiling])
+
+
+def _verify_window_case(tilings):
+    """The fused verify window equals the gathered rows of the full-width
+    output, and that output is unchanged by it, at each (pps, hps)."""
     B, H, KH, D, maxp, C = 2, 4, 2, 16, 4, 6
     S_w = 3
     args, _ = _chunk_case(B, H, KH, D, maxp, C, 31)
     rng = np.random.default_rng(32)
     widx = jnp.asarray(rng.integers(0, C, size=(B, S_w)), jnp.int32)
-    for pps in (1, 2):
-        full = paged_chunk_attention(*args, scale=D ** -0.5, interpret=True,
-                                     pages_per_step=pps)
-        out, win = paged_chunk_attention(*args, scale=D ** -0.5,
-                                         interpret=True, pages_per_step=pps,
-                                         logit_index=widx)
+    base = None
+    for pps, hps in tilings:
+        kw = dict(scale=D ** -0.5, interpret=True, pages_per_step=pps,
+                  heads_per_step=hps)
+        full = paged_chunk_attention(*args, **kw)
+        out, win = paged_chunk_attention(*args, logit_index=widx, **kw)
         assert np.array_equal(np.asarray(out), np.asarray(full)), pps
         want = jnp.take_along_axis(full, widx[:, :, None, None], axis=1)
         assert np.array_equal(np.asarray(win), np.asarray(want)), pps
+        base = np.asarray(full) if base is None else base
+        assert np.array_equal(np.asarray(full), base), (pps, hps)
+    return args, widx, D
+
+
+def test_fused_verify_window_matches_post_gather():
+    """logit_index mode: the kernel's fused window output must equal
+    gathering the same rows from the full-width output — bitwise — and the
+    full-width output itself must be unchanged by the extra operand."""
+    args, widx, D = _verify_window_case([(1, 1), (2, 1)])
     # ref agrees with its own gather
     rout, rwin = paged_chunk_attention_ref(*args, scale=D ** -0.5,
                                            logit_index=widx)
     want = jnp.take_along_axis(rout, widx[:, :, None, None], axis=1)
     assert np.array_equal(np.asarray(rwin), np.asarray(want))
+
+
+@pytest.mark.parametrize("tiling", [(2, 2), (3, 2), (None, None)])
+def test_fused_verify_window_all_heads(tiling):
+    """The fused window's epilogue loops over every head a step holds:
+    window rows and full-width output bit-for-bit the one-head kernel's."""
+    _verify_window_case([(1, 1), tiling])
 
 
 def test_paged_pool_append_quant_matches_f32_within_scale():
@@ -802,40 +931,49 @@ def test_kv_page_bytes_int8_capacity_ratio():
         assert ratio >= 1.9, (psize, KH, D, ratio)
 
 
+_ENGINE_RUNS = {}
+
+
+def _engine_run(kv_dtype="float32", pages_per_step=None):
+    """(engine, tokens per request) of three prompts served greedily by a
+    reduced qwen3-1.7b engine, once per (kv_dtype, pages_per_step)."""
+    from repro.configs.base import get_model_config, reduced
+    from repro.models import api
+    from repro.serving import Engine, EngineConfig
+
+    key = (kv_dtype, pages_per_step)
+    if key not in _ENGINE_RUNS:
+        cfg = reduced(get_model_config("qwen3-1.7b"))
+        params = api.model_init(jax.random.key(0), cfg)
+        rng = np.random.default_rng(3)
+        prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
+                   for n in (5, 9, 3)]
+        ecfg = EngineConfig(num_slots=2, num_pages=32, page_size=4,
+                            max_prompt_len=12, max_new_tokens=6,
+                            token_budget=16, policy="on_demand",
+                            kv_dtype=kv_dtype, compute_dtype="float32",
+                            pages_per_step=pages_per_step)
+        eng = Engine(cfg, params, ecfg)
+        for p in prompts:
+            eng.submit(p, 6)
+        fin = eng.run()
+        assert eng.pool.used_pages == 0
+        _ENGINE_RUNS[key] = eng, [list(r.out_tokens)
+                                  for r in sorted(fin, key=lambda r: r.id)]
+    return _ENGINE_RUNS[key]
+
+
 def test_engine_int8_and_pages_per_step():
     """End-to-end engine invariants of the new modes: pages_per_step > 1 is
     bit-identical to the classic engine; int8 pools build the 4-tuple
     (pages + scale sidecar) cache, stay pps-invariant, and greedy decode
     tracks the f32 engine within the documented divergence bound (exact
     token match is NOT expected: appends requantize whole pages)."""
-    from repro.configs.base import get_model_config, reduced
-    from repro.models import api
-    from repro.serving import Engine, EngineConfig
-
-    cfg = reduced(get_model_config("qwen3-1.7b"))
-    params = api.model_init(jax.random.key(0), cfg)
-    rng = np.random.default_rng(3)
-    prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
-               for n in (5, 9, 3)]
-
-    def run(**kw):
-        ecfg = EngineConfig(num_slots=2, num_pages=32, page_size=4,
-                            max_prompt_len=12, max_new_tokens=6,
-                            token_budget=16, policy="on_demand",
-                            kv_dtype=kw.pop("kv_dtype", "float32"),
-                            compute_dtype="float32", **kw)
-        eng = Engine(cfg, params, ecfg)
-        for p in prompts:
-            eng.submit(p, 6)
-        fin = eng.run()
-        assert eng.pool.used_pages == 0
-        return eng, [list(r.out_tokens)
-                     for r in sorted(fin, key=lambda r: r.id)]
-
-    _, base = run()
+    run = _engine_run
+    _, base = run(pages_per_step=1)
     _, pps2 = run(pages_per_step=2)
     assert pps2 == base, "pages_per_step changed f32 engine output"
-    q8_eng, q8 = run(kv_dtype="int8")
+    q8_eng, q8 = run(kv_dtype="int8", pages_per_step=1)
     _, q8pps = run(kv_dtype="int8", pages_per_step=4)
     assert q8pps == q8, "pages_per_step changed int8 engine output"
     leaves = jax.tree.leaves(q8_eng.cache)
@@ -848,3 +986,13 @@ def test_engine_int8_and_pages_per_step():
     # (near-uniform logits flip argmax on tiny perturbations); trained
     # checkpoints sit far above this
     assert match >= 0.5, f"int8 greedy diverged too far: {match:.2f}"
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_engine_derived_tiling_matches_one_page(kv_dtype):
+    """The engine's default (the tiling chunk_tiling derives per chunk
+    bucket, all kv heads per step) serves the same tokens as one page per
+    step, for f32 and int8 pools."""
+    eng, derived = _engine_run(kv_dtype=kv_dtype)
+    assert eng.ecfg.pages_per_step is None
+    assert derived == _engine_run(kv_dtype=kv_dtype, pages_per_step=1)[1]

@@ -22,6 +22,8 @@ from repro.kernels.paged_attention.kernel import paged_chunk_attention
 # the engine's default pool of 256 pages x 16 tokens, 8 slots
 B, H, KH, D, PSIZE, PAGES, MAXP = 8, 16, 8, 128, 16, 256, 33
 D_MODEL, D_FF, HORN_GROUPS = 2048, 6144, 4
+# the serving benchmark's engine: 20 slots, 1,601 pages, 128-page tables
+SERVE_B, SERVE_PAGES, SERVE_MAXP = 20, 1601, 128
 
 
 @pytest.fixture(scope="module")
@@ -56,9 +58,7 @@ def _compile(fn, one_chip, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("kv_dtype,C", [("bfloat16", 1), ("bfloat16", 16),
-                                        ("int8", 1)])
-def test_paged_chunk_attention_compiles(one_chip, kv_dtype, C):
+def _compile_paged(one_chip, kv_dtype, C, batch, pages, maxp):
     int8 = kv_dtype == "int8"
 
     def fn(q, kp, vp, bt, starts, clens, *scales):
@@ -66,12 +66,28 @@ def test_paged_chunk_attention_compiles(one_chip, kv_dtype, C):
         return paged_chunk_attention(q, kp, vp, bt, starts, clens,
                                      scale=D ** -0.5, **sc)
 
-    pool = ((PAGES, KH, PSIZE, D), jnp.dtype(kv_dtype))
-    shapes = [((B, C, H, D), jnp.bfloat16), pool, pool,
-              ((B, MAXP), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32)]
+    pool = ((pages, KH, PSIZE, D), jnp.dtype(kv_dtype))
+    shapes = [((batch, C, H, D), jnp.bfloat16), pool, pool,
+              ((batch, maxp), jnp.int32), ((batch,), jnp.int32),
+              ((batch,), jnp.int32)]
     if int8:
-        shapes += [((PAGES, KH), jnp.float32)] * 2
+        shapes += [((pages, KH), jnp.float32)] * 2
     _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("kv_dtype,C", [("bfloat16", 1), ("bfloat16", 16),
+                                        ("int8", 1)])
+def test_paged_chunk_attention_compiles(one_chip, kv_dtype, C):
+    _compile_paged(one_chip, kv_dtype, C, B, PAGES, MAXP)
+
+
+@pytest.mark.parametrize("kv_dtype,C", [("bfloat16", 1), ("bfloat16", 8),
+                                        ("bfloat16", 256), ("int8", 1)])
+def test_paged_chunk_attention_compiles_at_serving_cell(one_chip, kv_dtype,
+                                                        C):
+    """The serving benchmark's geometry with the derived tiling: the wide
+    C = 256 step's blocks and scratch must fit the chip's VMEM."""
+    _compile_paged(one_chip, kv_dtype, C, SERVE_B, SERVE_PAGES, SERVE_MAXP)
 
 
 def test_flash_attention_compiles(one_chip):
