@@ -10,7 +10,9 @@ directly-constructed :class:`Geometry`), prove per grid launch:
   over legitimate accumulator-carry dims (grid dims the out map is
   independent of *and* that are declared ``"arbitrary"``), covers each
   output block exactly once.  A revisit dim declared ``"parallel"`` is a
-  double-write by construction.
+  double-write by construction.  An output aliased to an input
+  (``input_output_aliases``) is an in-place update and is not held to
+  coverage: the blocks no step writes keep the input's values.
 * **HS004 consistency** — index-map arity vs array rank, block-shape rank,
   ``input_output_aliases`` dtype/shape agreement, positive scratch shapes.
 * **HS005 null-page contract** — block-table gathers must select the
@@ -186,7 +188,10 @@ def geometry_from_capture(cap: Capture, name: str,
     if isinstance(cp, dict) and cp.get("dimension_semantics") is not None:
         sem = tuple(cp["dimension_semantics"])
     aliases = kw.get("input_output_aliases")
-    aliases = dict(aliases) if aliases else None
+    # JAX numbers aliased inputs over every call operand, scalar-prefetch
+    # operands first; the geometry numbers the BlockSpec'd operands only
+    aliases = {i - nsp if isinstance(i, int) else i: o
+               for i, o in dict(aliases).items()} if aliases else None
     return Geometry(name=name, grid=tuple(grid), in_operands=ins,
                     out_operands=outs, scalar_tables=tables,
                     scratch=[s for s in scratch if isinstance(s, ScratchV)],
@@ -591,9 +596,13 @@ def verify(geom: Geometry) -> Report:
     v = _Verifier(geom)
     for op in geom.in_operands:
         v.check_operand(op)
+    aliased = {f"out{o}" for o in (geom.input_output_aliases or {}).values()}
     for op in geom.out_operands:
         idx = v.check_operand(op)
-        if idx is not None:
+        # an output aliased to an input is updated in place: blocks no
+        # grid step writes keep the input's values, so it has no holes
+        # (and may be written at rows a scalar table chooses)
+        if idx is not None and op.name not in aliased:
             v.check_coverage(op, idx)
     v.check_aliases()
     v.check_scratch()

@@ -142,16 +142,27 @@ def _dropout_entries() -> List[dict]:
 
 
 def _ssd_entries() -> List[dict]:
-    def build(B, S, H, P, N, **kw):
-        return ([FakeArray((B, S, H, P)), FakeArray((B, S, H)),
-                 FakeArray((H,)), FakeArray((B, S, N)),
-                 FakeArray((B, S, N))], kw)
+    def build(B, S, H, P, N, L=0, **kw):
+        # L > 0: the engine's pool of L layers' states, one row advanced
+        state = FakeArray((L, B, H, P, N) if L else (B, H, P, N))
+        args = [FakeArray((B, S, H, P)), FakeArray((B, S, H)),
+                FakeArray((H,)), FakeArray((B, S, N)),
+                FakeArray((B, S, N)), state, FakeArray((B,)),
+                Table("layer", (1,), 0, max(L, 1) - 1)]
+        return args, kw
 
     return [
         {"fn": "ssd_chunk_scan", "label": "ssd/chunked",
          "build": lambda: build(2, 24, 3, 4, 8, chunk=8)},
         {"fn": "ssd_chunk_scan", "label": "ssd/chunk-shrunk",
          "build": lambda: build(2, 24, 3, 4, 8, chunk=7)},
+        # 256-row chunks: head groups of 8 of 16 heads, a row of a
+        # 5-layer state pool
+        {"fn": "ssd_chunk_scan", "label": "ssd/head-groups",
+         "build": lambda: build(2, 256, 16, 4, 8, L=5)},
+        # the serving decode call: one position per slot
+        {"fn": "ssd_chunk_scan", "label": "ssd/decode",
+         "build": lambda: build(3, 1, 16, 4, 8, L=5)},
     ]
 
 

@@ -62,6 +62,8 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_chunk: int = 256
     ssm_conv_width: int = 4
+    ssm_conv_bias_bc: bool = False   # granite: the conv's bias covers the
+                                     # B and C channels too (x always has one)
 
     # --- enc-dec / multimodal --------------------------------------------------
     is_encoder_decoder: bool = False
@@ -82,6 +84,12 @@ class ModelConfig:
     dtype: str = "bfloat16"
     # gemma-style extra norms around sublayers (post-norms)
     post_sublayer_norm: bool = False
+    # granite's scalings: embeddings times ``embedding_multiplier``, each
+    # sublayer's output times ``residual_multiplier`` before its residual
+    # add, logits divided by ``logits_scaling`` (1.0 leaves a path as is)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # ------------------------------------------------------------------
     @property
@@ -137,6 +145,8 @@ class ModelConfig:
                 proj_in = 2 * d_in + 2 * self.ssm_state + nh   # z,x,B,C,dt
                 n += d * proj_in                                # in_proj
                 n += self.ssm_conv_width * (d_in + 2 * self.ssm_state)
+                n += d_in + (2 * self.ssm_state if self.ssm_conv_bias_bc
+                             else 0)                    # conv bias
                 n += 2 * nh + nh * 0 + d_in * d                 # A,D(+dt_bias), out_proj
                 n += d_in                                       # gated norm
             if self.layer_is_moe(i):
@@ -250,7 +260,7 @@ def _ensure_loaded() -> None:
     for mod in (
         "qwen3_1p7b", "qwen1p5_4b", "gemma2_27b", "gemma3_4b", "mamba2_2p7b",
         "llava_next_34b", "jamba_1p5_large", "whisper_base", "phi3p5_moe",
-        "llama4_maverick", "horn_mnist",
+        "llama4_maverick", "horn_mnist", "granite_4p0_h_micro",
     ):
         importlib.import_module(f"repro.configs.{mod}")
 
@@ -296,6 +306,10 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         norm=cfg.norm,
         tie_embeddings=cfg.tie_embeddings,
         post_sublayer_norm=cfg.post_sublayer_norm,
+        ssm_conv_bias_bc=cfg.ssm_conv_bias_bc,
+        embedding_multiplier=cfg.embedding_multiplier,
+        residual_multiplier=cfg.residual_multiplier,
+        logits_scaling=cfg.logits_scaling,
     )
     base.update(overrides)
     return ModelConfig(**base)

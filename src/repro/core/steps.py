@@ -246,7 +246,10 @@ def make_unified_paged_step(run: RunConfig, mesh, *, num_pages: int,
     ``C`` is the tick's chunk width: a decode-only tick runs at C == 1 (the
     classic paged-decode cell, bit-compatible with it); ticks carrying
     prompt chunks run at power-of-two C buckets (jit caches one executable
-    per width).  The pool is donated so the K/V append is in-place.  Greedy
+    per width).  The pool is donated so the K/V append is in-place; so is
+    a hybrid's per-slot Mamba state (the cache's "ssm" entry), which each
+    Mamba layer's SSD scan continues from and writes back in place (zero
+    state where a slot's chunk starts at position 0).  Greedy
     when ``temperature <= 0``; otherwise categorical with per-slot keys
     ``fold_in(fold_in(root_key, req_id), step)`` — no key is ever reused
     across requests or steps.  Idle slots (chunk_len 0) and mid-prompt
@@ -429,7 +432,9 @@ def make_unified_paged_step(run: RunConfig, mesh, *, num_pages: int,
     p_shard = tree_shardings(paxes, ctx)
     cache_struct = jax.eval_shape(
         lambda: T.init_paged_cache(cfg, num_pages, page_size,
-                                   dtype=kv_dtype))
+                                   dtype=kv_dtype,
+                                   num_slots=run.shape.global_batch,
+                                   conv_dtype=run.compute_dtype))
     variants = {
         flag: jax.jit(partial(unified_step, ensembles=flag),
                       in_shardings=(p_shard,) + (None,) * 13,

@@ -9,10 +9,12 @@ import jax.numpy as jnp
 f32 = jnp.float32
 
 
-def ssd_ref(x, dt, A, Bm, Cm):
-    """Token-by-token linear recurrence (exact, O(S) sequential).
+def ssd_ref(x, dt, A, Bm, Cm, initial_state=None):
+    """Token-by-token linear recurrence (exact, O(S) sequential), from
+    ``initial_state`` [B,H,P,N] (None: zeros).
 
-    x: [B,S,H,P]; dt: [B,S,H]; A: [H]; Bm, Cm: [B,S,N] -> y [B,S,H,P].
+    x: [B,S,H,P]; dt: [B,S,H]; A: [H]; Bm, Cm: [B,S,N]
+    -> (y [B,S,H,P] f32, final state [B,H,P,N] f32).
     """
     Bsz, S, H, P = x.shape
     N = Bm.shape[-1]
@@ -26,7 +28,8 @@ def ssd_ref(x, dt, A, Bm, Cm):
         y = jnp.einsum("bhpn,bn->bhp", state, Ct)
         return state, y
 
-    state0 = jnp.zeros((Bsz, H, P, N), f32)
+    state0 = jnp.zeros((Bsz, H, P, N), f32) if initial_state is None \
+        else initial_state.astype(f32)
     xs = (x.transpose(1, 0, 2, 3).astype(f32),
           dt.transpose(1, 0, 2).astype(f32),
           Bm.transpose(1, 0, 2).astype(f32),

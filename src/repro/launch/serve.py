@@ -75,10 +75,11 @@ import time
 
 import numpy as np
 
-from repro.configs.base import HornConfig, get_model_config, list_archs, \
-    reduced
+from repro.configs.base import (MAMBA, HornConfig, get_model_config,
+                                list_archs, reduced)
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
+from repro.models.params import cast_tree
 from repro.serving import Engine, EngineConfig, EngineOOM, ModelBank, Router
 from repro.serving.observability import (Telemetry, TraceRecorder,
                                          load_trace, parse_slo_class,
@@ -163,11 +164,13 @@ def make_parser() -> argparse.ArgumentParser:
     ap.add_argument("--policy", choices=["reserve", "on_demand"],
                     default="on_demand")
     ap.add_argument("--prefix-cache", action=argparse.BooleanOptionalAction,
-                    default=True,
+                    default=None,
                     help="content-addressed KV page reuse + COW: identical "
                          "prompt prefixes prefill once, ensembles share "
                          "their prompt pages across all circuits "
-                         "(--no-prefix-cache re-prefills per request)")
+                         "(--no-prefix-cache re-prefills per request; "
+                         "default: on, off for models with Mamba layers, "
+                         "whose per-slot state no page carries)")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--kv-dtype", choices=["bfloat16", "float32", "int8"],
                     default="bfloat16",
@@ -252,6 +255,11 @@ def build_engine(args):
         cfg = reduced(cfg)
     if cfg.family == "mlp":
         raise SystemExit("horn-mnist is a classifier; use launch.train")
+    if args.prefix_cache is None:
+        args.prefix_cache = MAMBA not in cfg.layer_pattern
+    # float32 weights where they and the step's bf16 copy of them (6 bytes
+    # a parameter) fit one 16 GB chip; else bf16, the compute dtype
+    param_dtype = "float32" if 6 * cfg.param_count() <= 16e9 else "bfloat16"
 
     ecfg = EngineConfig(
         num_slots=args.slots, num_pages=args.pages, page_size=args.page_size,
@@ -266,7 +274,11 @@ def build_engine(args):
         Sanitizer.install_jax_guards()      # before anything jits
         sanitizer = Sanitizer()
     import jax
-    params = api.model_init(jax.random.key(args.seed), cfg)
+    # made and cast in one program: a model kept in bf16 never holds its
+    # float32 weights whole
+    params = jax.jit(lambda k: cast_tree(api.model_init(k, cfg),
+                                         param_dtype))(
+        jax.random.key(args.seed))
     bank = router = None
     if args.submodels > 0:
         if args.submodels > args.slots and args.ensemble_frac > 0:
@@ -352,7 +364,8 @@ def main() -> None:
           f"{args.ensemble_frac:.0%} ensemble)" if bank else ""
     print(f"serving {args.requests} requests ({args.stream} stream, "
           f"{args.slots} slots, {args.pages}x{args.page_size}-token pages, "
-          f"budget {ecfg.token_budget} tok/tick, policy={args.policy}{sub})")
+          f"budget {engine.ecfg.token_budget} tok/tick, "
+          f"policy={args.policy}{sub})")
 
     t0 = time.monotonic()
     max_running = 0

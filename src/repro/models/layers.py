@@ -298,6 +298,8 @@ def embed_apply(params, tokens, cfg: ModelConfig, ctx: ShardingCtx):
     x = jnp.take(params["embedding"], tokens, axis=0).astype(cfg.dtype)
     if cfg.post_sublayer_norm:   # gemma family scales embeddings
         x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
+    if cfg.embedding_multiplier != 1.0:                  # granite
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     return ctx.constrain(x, "batch", "seq", "act_embed")
 
 
@@ -306,6 +308,8 @@ def unembed_apply(params, x, cfg: ModelConfig, ctx: ShardingCtx):
     if w is None:
         w = params["embedding"].T
     logits = jnp.einsum("...d,dv->...v", x, w.astype(x.dtype))
+    if cfg.logits_scaling != 1.0:                        # granite
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     if cfg.final_logit_softcap:
         c = cfg.final_logit_softcap
         logits = jnp.tanh(logits / c) * c

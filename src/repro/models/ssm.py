@@ -46,6 +46,9 @@ def mamba_specs(cfg: ModelConfig):
         "conv_x_bias": ParamSpec((d_in,), ("ssm_inner",), "zeros"),
         "conv_B": ParamSpec((W, N), ("conv", "ssm_state"), "normal", 0.5),
         "conv_C": ParamSpec((W, N), ("conv", "ssm_state"), "normal", 0.5),
+        **({"conv_B_bias": ParamSpec((N,), ("ssm_state",), "zeros"),
+            "conv_C_bias": ParamSpec((N,), ("ssm_state",), "zeros")}
+           if cfg.ssm_conv_bias_bc else {}),
         "gnorm": ParamSpec((d_in,), ("ssm_inner",), "zeros"),
         "wo": ParamSpec((d_in, d), ("ssm_inner", "embed")),
     }
@@ -103,17 +106,20 @@ def ssd_chunked(x, dt, A, Bm, Cm, *, chunk: int, initial_state=None):
         initial_state = jnp.zeros((B, H, P, N), f32)
 
     idx = jnp.arange(Q)
-    causal = (idx[:, None] >= idx[None, :]).astype(f32)            # [Q, Q]
+    causal = idx[:, None] >= idx[None, :]                          # [Q, Q]
 
     def step(state, inp):
         xq, dtq, Bq, Cq = inp                   # [B,Q,H,P],[B,Q,H],[B,Q,N]x2
         xq = xq.astype(f32)
-        dA = dtq * Af                            # [B,Q,H] (negative)
+        dA = dtq * Af[None, None]                # [B,Q,H] (negative)
         cum = jnp.cumsum(dA, axis=1)             # [B,Q,H]
         # --- intra-chunk (dual quadratic form) ---
         CB = jnp.einsum("bin,bjn->bij", Cq, Bq)  # [B,Q,Q]
-        decay = jnp.exp(cum[:, :, None] - cum[:, None, :])          # [B,Q,Q,H]
-        T = CB[..., None] * decay * causal[None, :, :, None] * dtq[:, None]
+        # masked before the exp: above the diagonal cum_i - cum_j > 0 grows
+        # with the chunk and overflows to inf (and inf * 0 is nan)
+        seg = jnp.where(causal[None, :, :, None],
+                        cum[:, :, None] - cum[:, None, :], -jnp.inf)
+        T = CB[..., None] * jnp.exp(seg) * dtq[:, None]             # [B,Q,Q,H]
         y = jnp.einsum("bijh,bjhp->bihp", T, xq)
         # --- contribution of carried state ---
         y = y + jnp.einsum("bin,bhpn,bih->bihp", Cq, state, jnp.exp(cum))
@@ -154,8 +160,24 @@ def _gated_norm(params, y, z, cfg: ModelConfig):
     """RMSNormGated: RMSNorm(y * silu(z)) * (1 + w)."""
     g = (y * jax.nn.silu(z)).astype(f32)
     var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-    out = g * jax.lax.rsqrt(var + cfg.norm_eps) * (1.0 + params["gnorm"].astype(f32))
+    w = 1.0 + params["gnorm"].astype(f32)
+    out = g * jax.lax.rsqrt(var + cfg.norm_eps) \
+        * w.reshape((1,) * (g.ndim - 1) + (-1,))
     return out.astype(y.dtype)
+
+
+def _in_proj(params, x):
+    """(z, x, B, C, dt after softplus (f32), A (f32)) of the mixer's
+    input projection."""
+    z = jnp.einsum("bsd,de->bse", x, params["wz"])
+    xs = jnp.einsum("bsd,de->bse", x, params["wx"])
+    Bs = jnp.einsum("bsd,dn->bsn", x, params["wB"])
+    Cs = jnp.einsum("bsd,dn->bsn", x, params["wC"])
+    dt = jnp.einsum("bsd,dh->bsh", x, params["wdt"])
+    dt = jax.nn.softplus(dt.astype(f32)
+                         + params["dt_bias"].astype(f32)[None, None])
+    A = -jnp.exp(params["A_log"].astype(f32))
+    return z, xs, Bs, Cs, dt, A
 
 
 def mamba_apply(params, x, cfg: ModelConfig, ctx: ShardingCtx, *,
@@ -168,19 +190,14 @@ def mamba_apply(params, x, cfg: ModelConfig, ctx: ShardingCtx, *,
     """
     B, S, _ = x.shape
     d_in, H, P, N = ssm_dims(cfg)
-
-    z = jnp.einsum("bsd,de->bse", x, params["wz"])
-    xs = jnp.einsum("bsd,de->bse", x, params["wx"])
-    Bs = jnp.einsum("bsd,dn->bsn", x, params["wB"])
-    Cs = jnp.einsum("bsd,dn->bsn", x, params["wC"])
-    dt = jnp.einsum("bsd,dh->bsh", x, params["wdt"])
-    dt = jax.nn.softplus(dt.astype(f32) + params["dt_bias"].astype(f32))
-    A = -jnp.exp(params["A_log"].astype(f32))
+    z, xs, Bs, Cs, dt, A = _in_proj(params, x)
 
     if cache is None:
         xs = jax.nn.silu(causal_conv(xs, params["conv_x"], params["conv_x_bias"]))
-        Bs = jax.nn.silu(causal_conv(Bs, params["conv_B"]))
-        Cs = jax.nn.silu(causal_conv(Cs, params["conv_C"]))
+        Bs = jax.nn.silu(causal_conv(Bs, params["conv_B"],
+                                     params.get("conv_B_bias")))
+        Cs = jax.nn.silu(causal_conv(Cs, params["conv_C"],
+                                     params.get("conv_C_bias")))
         if channel_mask is not None:
             xs = xs * channel_mask.astype(xs.dtype)
         xs = ctx.constrain(xs, "batch", "seq", "ssm_inner")
@@ -196,8 +213,10 @@ def mamba_apply(params, x, cfg: ModelConfig, ctx: ShardingCtx, *,
         cx, cB, cC = jnp.split(conv_state, [d_in, d_in + N], axis=-1)
         xs1, cx = conv_decode_step(cx, xs[:, 0], params["conv_x"],
                                    params["conv_x_bias"])
-        Bs1, cB = conv_decode_step(cB, Bs[:, 0], params["conv_B"])
-        Cs1, cC = conv_decode_step(cC, Cs[:, 0], params["conv_C"])
+        Bs1, cB = conv_decode_step(cB, Bs[:, 0], params["conv_B"],
+                                   params.get("conv_B_bias"))
+        Cs1, cC = conv_decode_step(cC, Cs[:, 0], params["conv_C"],
+                                   params.get("conv_C_bias"))
         xs1, Bs1, Cs1 = map(jax.nn.silu, (xs1, Bs1, Cs1))
         if channel_mask is not None:
             xs1 = xs1 * channel_mask[:, 0].astype(xs1.dtype)
@@ -211,3 +230,64 @@ def mamba_apply(params, x, cfg: ModelConfig, ctx: ShardingCtx, *,
     y = _gated_norm(params, y, z, cfg)
     out = jnp.einsum("bse,ed->bsd", y, params["wo"])
     return ctx.constrain(out, "batch", "seq", "act_embed"), new_cache
+
+
+def padding_masked(dt, chunk_lens):
+    """dt [B, C, H] with 0 at each slot's positions past ``chunk_lens``:
+    there exp(0 A) = 1 and dt x B^T = 0, so the state passes unchanged."""
+    valid = jnp.arange(dt.shape[1])[None, :] < chunk_lens[:, None]
+    return jnp.where(valid[..., None], dt, 0.0)
+
+
+def mamba_paged(params, x, cfg: ModelConfig, ctx: ShardingCtx, *, cache,
+                index, starts, chunk_lens):
+    """Mamba2 mixer in the unified serving step: slot b's chunk x[b]
+    ([B, C, d], ``chunk_lens[b]`` real positions, the rest padding)
+    continues from the slot's stored state.
+
+    cache = the Mamba layers' state pool (conv tails [Lm, B, W-1,
+    d_in + 2N] -- the last W-1 pre-conv x, B, C rows each slot has seen
+    --, SSM states [Lm, B, H, P, N] f32), of which this layer owns row
+    ``index``.  A slot whose chunk starts at position 0 (``starts == 0``
+    with real positions: a fresh or recomputed sequence) starts from
+    zeros; a slot with no positions this tick (the engine fills its
+    ``starts`` and ``chunk_lens`` with 0) keeps its state.  Padded
+    positions take dt = 0, so the state passes through them unchanged; the
+    new conv tail is the W-1 rows ending at the chunk's last real position
+    (the old tail at chunk_len 0).  Returns (out [B, C, d], the pool with row
+    ``index`` advanced in place).
+    """
+    B, C, _ = x.shape
+    d_in, H, P, N = ssm_dims(cfg)
+    W = cfg.ssm_conv_width
+    z, xs, Bs, Cs, dt, A = _in_proj(params, x)
+    tails, states = cache
+    tail = jax.lax.dynamic_index_in_dim(tails, index, 0, keepdims=False)
+    fresh = (starts == 0) & (chunk_lens > 0)
+    tail = jnp.where(fresh[:, None, None], jnp.zeros_like(tail), tail)
+    xbc = jnp.concatenate([xs, Bs, Cs], axis=-1)
+    full = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+    weight = jnp.concatenate(
+        [params["conv_x"], params["conv_B"], params["conv_C"]], axis=-1)
+    bias = jnp.concatenate(
+        [params["conv_x_bias"],
+         params.get("conv_B_bias", jnp.zeros((N,), weight.dtype)),
+         params.get("conv_C_bias", jnp.zeros((N,), weight.dtype))])
+    conv = sum(full[:, w:w + C] * weight[w][None, None] for w in range(W)) \
+        + bias[None, None]
+    xs, Bs, Cs = jnp.split(jax.nn.silu(conv), [d_in, d_in + N], axis=-1)
+    rows = chunk_lens[:, None] + jnp.arange(W - 1)[None, :]       # [B, W-1]
+    new_tail = jnp.take_along_axis(full, rows[..., None], axis=1)
+    tails = jax.lax.dynamic_update_index_in_dim(
+        tails, new_tail.astype(tails.dtype), index, 0)
+    dt = padding_masked(dt, chunk_lens)
+    xh = xs.reshape(B, C, H, P)
+    # on the chip the kernel reads and writes row ``index`` of the
+    # (donated) pool in place, zeroing a fresh slot's state as it reads it
+    from repro.kernels.ssd.ops import ssd_chunk_scan
+    y, states = ssd_chunk_scan(xh, dt, A, Bs, Cs, states, fresh, index,
+                               chunk=cfg.ssm_chunk)
+    y = y + xh * params["D"].astype(y.dtype)[None, None, :, None]
+    y = _gated_norm(params, y.reshape(B, C, d_in), z, cfg)
+    out = jnp.einsum("bse,ed->bsd", y, params["wo"])
+    return ctx.constrain(out, "batch", "seq", "act_embed"), (tails, states)

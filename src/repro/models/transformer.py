@@ -23,7 +23,8 @@ from repro.launch.mesh import ShardingCtx
 from repro.models import layers as L
 from repro.models.attention import attn_apply, attn_specs
 from repro.models.params import ParamSpec, init_params, param_axes, stack_specs
-from repro.models.ssm import mamba_apply, mamba_specs, ssm_dims
+from repro.models.ssm import (mamba_apply, mamba_paged, mamba_specs,
+                              ssm_dims)
 
 f32 = jnp.float32
 
@@ -106,8 +107,12 @@ def _serve_slice(serve_masks, key: str, layer_idx):
 def _block_apply(bp, x, cfg: ModelConfig, ctx: ShardingCtx, *, kind: str,
                  is_moe: bool, layer_idx, horn, positions, cache,
                  cache_index, encoder_out=None, causal: bool = True,
-                 block_tables=None, chunk_lens=None, serve_masks=None):
-    """Returns (x, new_mix_cache, aux)."""
+                 block_tables=None, chunk_lens=None, serve_masks=None,
+                 ssm_index=None):
+    """Returns (x, new_mix_cache, aux).  In the unified serving step a
+    Mamba layer's ``cache`` is the whole per-slot state pool
+    (``init_paged_cache``'s "ssm") and ``ssm_index`` its row of it; the
+    pool comes back as ``new_mix_cache``."""
     B = x.shape[0]
     aux: Dict[str, Any] = {}
     h = L.norm_apply(bp["pre_norm"], x, cfg)
@@ -120,6 +125,10 @@ def _block_apply(bp, x, cfg: ModelConfig, ctx: ShardingCtx, *, kind: str,
             bp["attn"], h, cfg, ctx, kind=kind, positions=positions,
             cache=cache, cache_index=cache_index, head_mask=hm, causal=causal,
             block_tables=block_tables, chunk_lens=chunk_lens)
+    elif block_tables is not None:
+        out, new_mix_cache = mamba_paged(
+            bp["mamba"], h, cfg, ctx, cache=cache, index=ssm_index,
+            starts=cache_index, chunk_lens=chunk_lens)
     else:
         d_in = ssm_dims(cfg)[0]
         cm = pdrop.unit_mask(horn, layer_idx, B, d_in, salt=3)
@@ -127,7 +136,7 @@ def _block_apply(bp, x, cfg: ModelConfig, ctx: ShardingCtx, *, kind: str,
             bp["mamba"], h, cfg, ctx, cache=cache, channel_mask=cm)
     if cfg.post_sublayer_norm:
         out = L.norm_apply(bp["post_mixer_norm"], out, cfg)
-    x = x + out.astype(x.dtype)
+    x = x + _residual_scaled(out, cfg).astype(x.dtype)
 
     if "cross_attn" in bp and encoder_out is not None:
         h = L.norm_apply(bp["cross_norm"], x, cfg)
@@ -155,8 +164,16 @@ def _block_apply(bp, x, cfg: ModelConfig, ctx: ShardingCtx, *, kind: str,
                               mask_blocks=mb)
         if cfg.post_sublayer_norm:
             out = L.norm_apply(bp["post_ffn_norm"], out, cfg)
-        x = x + out.astype(x.dtype)
+        x = x + _residual_scaled(out, cfg).astype(x.dtype)
     return x, new_mix_cache, aux
+
+
+def _residual_scaled(out, cfg: ModelConfig):
+    """A sublayer's output as it joins the residual stream (granite scales
+    it by ``residual_multiplier``; at 1.0 it is left untouched)."""
+    if cfg.residual_multiplier == 1.0:
+        return out
+    return out * jnp.asarray(cfg.residual_multiplier, out.dtype)
 
 
 def _empty_aux():
@@ -199,7 +216,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
 
 
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
-                     dtype=jnp.bfloat16):
+                     dtype=jnp.bfloat16, *, num_slots: int = 0,
+                     conv_dtype=jnp.bfloat16):
     """Block-paged decode caches: every attention layer gets a pool of
     ``num_pages`` fixed-size pages [P, KH, psize, D] addressed through a
     shared per-sequence block table (page ids are layer-agnostic: page j of
@@ -211,15 +229,25 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     (k_pages int8, v_pages int8, k_scale f32 [P, KH], v_scale f32 [P, KH])
     — one symmetric scale per (page, kv-head) stored beside the pool, so a
     page costs ~1/2 the HBM of bf16 (~1/4 of f32) and the sidecar follows
-    the page through every COW copy (same page ids index both arrays)."""
+    the page through every COW copy (same page ids index both arrays).
+
+    Mamba layers hold no pages (their entries are None): all of them
+    share one per-slot state pool beside the page pools, ``cache["ssm"]``
+    = (conv tail [Lm, num_slots, W-1, d_inner + 2N] in ``conv_dtype``, SSM
+    state [Lm, num_slots, H, P, N] float32), one row per Mamba layer in
+    layer order, slot b's state belonging to whatever sequence runs in slot
+    b (``ssm.mamba_paged``).  One array per kind, carried whole through the
+    layer scan, so each layer's update lands in place."""
     kv, hd = cfg.num_kv_heads, cfg.head_dim
     quantized = jnp.dtype(dtype) == jnp.int8
 
     def mix_cache(kind):
+        if kind == MAMBA:
+            return None
         if kind not in (ATTN, LOCAL):
             raise ValueError(
-                f"paged KV cache supports attention mixers only, got {kind!r} "
-                "(SSM states are slot-resident, not paged — see ROADMAP)")
+                f"paged KV cache supports attention and Mamba mixers, got "
+                f"{kind!r}")
         pools = (jnp.zeros((num_pages, kv, page_size, hd), dtype),
                  jnp.zeros((num_pages, kv, page_size, hd), dtype))
         if quantized:
@@ -236,6 +264,16 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
     if cfg.pattern_remainder:
         cache["rem"] = {f"r{i}": mix_cache(cfg.layer_pattern[i])
                         for i in range(cfg.pattern_remainder)}
+    n_mamba = sum(k == MAMBA for k in cfg.layer_kinds())
+    if n_mamba:
+        if num_slots <= 0:
+            raise ValueError("Mamba layers keep one state per slot: pass "
+                             "num_slots")
+        d_in, H, P, N = ssm_dims(cfg)
+        cache["ssm"] = (
+            jnp.zeros((n_mamba, num_slots, cfg.ssm_conv_width - 1,
+                       d_in + 2 * N), conv_dtype),
+            jnp.zeros((n_mamba, num_slots, H, P, N), f32))
     return cache
 
 
@@ -326,60 +364,75 @@ def lm_forward(params, tokens, cfg: ModelConfig, ctx: ShardingCtx, *,
     R = cfg.pattern_repeats
     new_cache: Dict[str, Any] = {}
     aux0 = _empty_aux()
+    # the unified serving step's Mamba state pool, carried whole through
+    # the layers; Mamba layer k of the stack (in layer order) owns row k
+    ssm = cache.get("ssm") if decode else None
+    n_m = sum(k == MAMBA for k in pat)
 
-    def superblock(x, aux_acc, sb_params, sb_cache, r):
+    def superblock(x, aux_acc, sb_params, sb_cache, r, ssm=None):
         caches_out = {}
+        j = 0
         for i, kind in enumerate(pat):
             li = r * len(pat) + i
+            own = ssm is not None and kind == MAMBA
             x, mix_c, aux = _block_apply(
                 sb_params[f"l{i}"], x, cfg, ctx, kind=kind,
                 is_moe=cfg.layer_is_moe(i), layer_idx=li, horn=horn,
                 positions=positions,
-                cache=None if sb_cache is None else sb_cache[f"l{i}"],
+                cache=ssm if own else
+                None if sb_cache is None else sb_cache[f"l{i}"],
                 cache_index=cache_index, encoder_out=encoder_out,
                 causal=causal, block_tables=block_tables,
-                chunk_lens=chunk_lens, serve_masks=serve_masks)
+                chunk_lens=chunk_lens, serve_masks=serve_masks,
+                ssm_index=r * n_m + j if own else None)
+            if own:
+                ssm, mix_c, j = mix_c, None, j + 1
             caches_out[f"l{i}"] = mix_c
             aux_acc = jax.tree.map(jnp.add, aux_acc, _pad_aux(aux))
-        return x, aux_acc, caches_out
+        return x, aux_acc, caches_out, ssm
 
     if R:
-        if decode:
-            def body(carry, inp):
-                x, acc = carry
-                sb_params, sb_cache, r = inp
-                x, acc, caches = superblock(x, acc, sb_params, sb_cache, r)
-                return (x, acc), caches
-            xs = (params["blocks"], cache["blocks"], jnp.arange(R))
-        else:
-            def body(carry, inp):
-                x, acc = carry
-                sb_params, r = inp
-                x, acc, caches = superblock(x, acc, sb_params, None, r)
-                return (x, acc), caches
-            xs = (params["blocks"], jnp.arange(R))
+        # one scan body for every mode: ``ssm`` is the Mamba state pool
+        # in the unified step and None (no carry leaves) otherwise
+        def body(carry, inp):
+            x, acc, ssm = carry
+            sb_params, sb_cache, r = inp
+            x, acc, caches, ssm = superblock(x, acc, sb_params, sb_cache, r,
+                                             ssm)
+            return (x, acc, ssm), caches
+        xs = (params["blocks"], cache["blocks"] if decode else None,
+              jnp.arange(R))
         if remat and mode == "train":
             body = jax.checkpoint(body)
-        (x, aux0), caches_stacked = jax.lax.scan(body, (x, aux0), xs)
+        (x, aux0, ssm), caches_stacked = jax.lax.scan(body, (x, aux0, ssm),
+                                                      xs)
         if mode != "train":
             new_cache["blocks"] = caches_stacked
 
     if cfg.pattern_remainder:
         rem_cache = {}
+        j = R * n_m
         for i in range(cfg.pattern_remainder):
             li = R * len(pat) + i
+            own = ssm is not None and pat[i] == MAMBA
             x, mix_c, aux = _block_apply(
                 params["rem"][f"r{i}"], x, cfg, ctx, kind=pat[i],
                 is_moe=cfg.layer_is_moe(li), layer_idx=li, horn=horn,
                 positions=positions,
-                cache=None if not decode else cache["rem"][f"r{i}"],
+                cache=ssm if own else
+                None if not decode else cache["rem"][f"r{i}"],
                 cache_index=cache_index, encoder_out=encoder_out,
                 causal=causal, block_tables=block_tables,
-                chunk_lens=chunk_lens, serve_masks=serve_masks)
+                chunk_lens=chunk_lens, serve_masks=serve_masks,
+                ssm_index=j if own else None)
+            if own:
+                ssm, mix_c, j = mix_c, None, j + 1
             rem_cache[f"r{i}"] = mix_c
             aux0 = jax.tree.map(jnp.add, aux0, _pad_aux(aux))
         if mode != "train":
             new_cache["rem"] = rem_cache
+    if ssm is not None:
+        new_cache["ssm"] = ssm
 
     if logit_index is not None:
         x = jnp.take_along_axis(

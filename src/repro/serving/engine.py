@@ -69,6 +69,7 @@ drafted tokens are free."""
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple, Union
@@ -77,8 +78,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import (ATTN, LOCAL, HornConfig, ModelConfig,
-                                RunConfig, ShapeConfig)
+from repro.configs.base import (ATTN, LOCAL, MAMBA, HornConfig,
+                                ModelConfig, RunConfig, ShapeConfig)
 from repro.core import steps as S
 from repro.models import transformer as T
 from repro.serving.block_table import (BlockTableMirror, marshal_i32,
@@ -170,11 +171,35 @@ class Engine:
                  router: Optional[Router] = None,
                  draft: Optional[DraftModel] = None,
                  telemetry: Optional[Telemetry] = None):
-        bad = [k for k in cfg.layer_pattern if k not in (ATTN, LOCAL)]
+        bad = [k for k in cfg.layer_pattern
+               if k not in (ATTN, LOCAL, MAMBA)]
         if bad or cfg.is_encoder_decoder or cfg.num_patches or cfg.learned_pos:
             raise ValueError(
-                f"paged serving supports decoder-only attention LMs; "
-                f"{cfg.name} has {bad or 'an unsupported input frontend'}")
+                f"paged serving supports decoder-only attention and Mamba "
+                f"LMs; {cfg.name} has "
+                f"{bad or 'an unsupported input frontend'}")
+        self.mamba_layers = sum(k == MAMBA for k in cfg.layer_kinds())
+        if self.mamba_layers:
+            # state bytes one live slot's scans read and write in a tick:
+            # the f32 SSM state and the conv tail of every Mamba layer,
+            # each way (transformer.init_paged_cache's "ssm")
+            from repro.models.ssm import ssm_dims
+            d_in, H, P, N = ssm_dims(cfg)
+            tail = (cfg.ssm_conv_width - 1) * (d_in + 2 * N) \
+                * jnp.dtype(ecfg.compute_dtype).itemsize
+            self._ssm_slot_tick_bytes = \
+                2 * self.mamba_layers * (H * P * N * 4 + tail)
+            # a Mamba layer's state is one running sum per slot: nothing
+            # can adopt it (prefix pages), roll it back (a rejected draft
+            # tail) or share it between circuits
+            held = [what for what, on in (
+                ("prefix_cache", ecfg.prefix_cache),
+                ("speculation", ecfg.speculate_k > 0 or draft is not None),
+                ("a ModelBank", bank is not None)) if on]
+            if held:
+                raise ValueError(
+                    f"{cfg.name} has Mamba layers, whose per-slot state "
+                    f"cannot carry {', '.join(held)}")
         if ecfg.max_prompt_len % ecfg.page_size:
             raise ValueError("max_prompt_len must be page-aligned")
         if ecfg.token_budget < ecfg.num_slots:
@@ -245,7 +270,9 @@ class Engine:
                 "unified_step", self._step, key_fn=_unified_step_key)
         self._page_copy = S.make_page_copy_step()
         self.cache = T.init_paged_cache(cfg, ecfg.num_pages, ecfg.page_size,
-                                        dtype=jnp.dtype(ecfg.kv_dtype))
+                                        dtype=jnp.dtype(ecfg.kv_dtype),
+                                        num_slots=ecfg.num_slots,
+                                        conv_dtype=ecfg.compute_dtype)
 
         B = ecfg.num_slots
         # chunk widths are clamped so every compile cell is a power of two
@@ -330,7 +357,16 @@ class Engine:
         pool — benchmarks warm up on the engine they measure (a fresh Engine
         would also mean a fresh jit cache) and then discard the warmup's
         contribution here.  Telemetry (histograms, traces, timeline, SLO
-        scores) resets with the counters."""
+        scores) resets with the counters.
+
+        This is the warm-up boundary, so it also moves every object set-up
+        left alive (traced programs, compile caches, weights' wrappers)
+        out of the cyclic collector's reach (``gc.freeze``): a full
+        collection inside the serving loop then walks only what serving
+        allocated, where it walked the whole set-up heap in the middle of
+        a tick (about 0.2 s for granite-4.0-h-micro on a v5e host)."""
+        gc.collect()
+        gc.freeze()
         self.stats.reset()
         if self.spec is not None:
             self.spec.draft_calls = 0
@@ -750,6 +786,9 @@ class Engine:
                 counts = self._tick_counts(entries, C)
                 self.real_tokens += counts["real_tokens"]
                 self.padded_tokens += counts["padded_tokens"]
+                if self.mamba_layers:
+                    self.ssm_state_bytes += counts["ssm_state_bytes"]
+                    self.ssm_tokens += counts["ssm_tokens"]
                 tick_span.set_metadata(**counts)
 
                 # ticks without an ensemble group skip the on-device
@@ -774,11 +813,14 @@ class Engine:
                         d_vote_flags, d_draft_lens, draft_probs,
                         self._root_key, ensembles=ensembles)
                 with _annotate("engine:sync"):
-                    # one deliberate host pull commits the tick: both
-                    # outputs in a single transfer instead of two
-                    # sequential np.asarray blocks
-                    sampled, accepted = jax.device_get(  # hornlint: sync-ok
-                        (sampled, accepted))
+                    # one deliberate host pull commits the tick; the
+                    # accepted counts matter only where a slot drafted,
+                    # so a tick without drafts pulls the tokens alone
+                    if spec_units:
+                        sampled, accepted = jax.device_get(  # hornlint: sync-ok
+                            (sampled, accepted))
+                    else:
+                        sampled = jax.device_get(sampled)  # hornlint: sync-ok
                 m_dev = pc()
             with _annotate("engine:commit"):
                 self.steps += 1
@@ -867,23 +909,33 @@ class Engine:
         padded to the chunk width ``chunk``), ``live_slots`` the slots
         that carry a chunk; ``attn_grid_steps`` the grid steps of one
         layer's paged-attention call, ``attn_live_steps`` those that hold
-        at least one live page (their ratio is the dead-step share)."""
+        at least one live page (their ratio is the dead-step share).  A
+        model with Mamba layers adds ``ssm_state_bytes``, the per-slot
+        state the live slots' scans read and write (SSM state and conv
+        tail, each way, over every Mamba layer), and ``ssm_tokens``, the
+        real tokens through the scan."""
         pps, hps = self._attn_tiling(C)
         groups = self.cfg.num_kv_heads // hps
         psize = self.ecfg.page_size
         live_pages = [-(-(e.start + e.chunk_len) // psize)
                       for e in entries.values()]
         live = sum(-(-n // pps) for n in live_pages)
-        return {"tick": self.steps,
-                "real_tokens": sum(e.chunk_len for e in entries.values()),
-                "prefill_tokens": sum(e.chunk_len for e in entries.values()
-                                      if e.req.in_prefill),
-                "padded_tokens": self.ecfg.num_slots * C,
-                "chunk": C,
-                "live_slots": len(entries),
-                "attn_grid_steps": self.ecfg.num_slots * groups
-                * -(-self.max_pages_per_seq // pps),
-                "attn_live_steps": live * groups}
+        real = sum(e.chunk_len for e in entries.values())
+        counts = {"tick": self.steps,
+                  "real_tokens": real,
+                  "prefill_tokens": sum(e.chunk_len for e in entries.values()
+                                        if e.req.in_prefill),
+                  "padded_tokens": self.ecfg.num_slots * C,
+                  "chunk": C,
+                  "live_slots": len(entries),
+                  "attn_grid_steps": self.ecfg.num_slots * groups
+                  * -(-self.max_pages_per_seq // pps),
+                  "attn_live_steps": live * groups}
+        if self.mamba_layers:
+            counts["ssm_state_bytes"] = \
+                len(entries) * self._ssm_slot_tick_bytes
+            counts["ssm_tokens"] = real
+        return counts
 
     def _commit_spec(self, slot: int, e: _Entry, sampled: int, acc: int,
                      now: float) -> None:

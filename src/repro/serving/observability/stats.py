@@ -43,6 +43,9 @@ class EngineStats:
     spec_drafted: int = 0            # draft tokens the parent verified
     spec_accepted: int = 0           # drafts that survived verification
     spec_committed: int = 0          # tokens committed by verify ticks
+    # Mamba layers' per-slot state (models with Mamba layers only)
+    ssm_state_bytes: int = 0         # state bytes the scans read + wrote
+    ssm_tokens: int = 0              # real tokens through the scans
 
     def reset(self) -> None:
         """Restore every field to its declared default.  Derived from

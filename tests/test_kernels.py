@@ -100,10 +100,47 @@ def test_ssd_kernel_sweep(B, S, H, P, N, chunk):
     A = -jnp.asarray(np.abs(rng.normal(size=(H,))) + 0.5, jnp.float32)
     Bm = jnp.asarray(rng.normal(size=(B, S, N)), jnp.float32) * 0.5
     Cm = jnp.asarray(rng.normal(size=(B, S, N)), jnp.float32) * 0.5
-    out = ssd_chunk_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
+    out, _ = ssd_chunk_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     ref, _ = ssd_ref(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-4, rtol=1e-3)
+
+
+@pytest.mark.parametrize("C", [1, 256])
+def test_ssd_kernel_carries_state(C):
+    """The serving step's call: each slot continues from its stored state
+    and the kernel returns the state after the chunk.  Slot 0 runs the
+    whole chunk, slot 1 a third of it from zero state (a fresh sequence;
+    padding past it takes dt = 0), and slot 2 none of it (chunk_len 0: its
+    state must come back unchanged).  The state is a two-layer pool of
+    which the call advances one layer and leaves the other as it was."""
+    rng = np.random.default_rng(C)
+    B, H, P, N = 3, 16, 8, 16
+    lens = np.array([C, max(1, C // 3), 0])
+    x = jnp.asarray(rng.normal(size=(B, C, H, P)), jnp.float32) * 0.5
+    dt = np.abs(rng.normal(size=(B, C, H))) + 0.1
+    dt[np.arange(C)[None, :] >= lens[:, None]] = 0.0
+    dt = jnp.asarray(dt, jnp.float32)
+    A = -jnp.asarray(np.abs(rng.normal(size=(H,))) + 0.5, jnp.float32)
+    Bm = jnp.asarray(rng.normal(size=(B, C, N)), jnp.float32) * 0.5
+    Cm = jnp.asarray(rng.normal(size=(B, C, N)), jnp.float32) * 0.5
+    # the pool of two layers' states; the call advances layer 1
+    pool = jnp.asarray(rng.normal(size=(2, B, H, P, N)), jnp.float32)
+    s0 = pool[1]
+    # slot 1 starts a fresh sequence: its stored state is not read
+    keep = jnp.asarray([1.0, 0.0, 1.0], jnp.float32)
+    y, out = ssd_chunk_scan(x, dt, A, Bm, Cm, pool, keep,
+                            jnp.asarray([1], jnp.int32), interpret=True)
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(pool[0]))
+    s1 = out[1]
+    yr, sr = ssd_ref(x, dt, A, Bm, Cm, s0.at[1].set(0.0))
+    for b, n in enumerate(lens):
+        np.testing.assert_allclose(np.asarray(y[b, :n]),
+                                   np.asarray(yr[b, :n]),
+                                   atol=2e-4, rtol=1e-3)
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(sr),
+                               atol=2e-4, rtol=1e-3)
+    np.testing.assert_array_equal(np.asarray(s1[2]), np.asarray(s0[2]))
 
 
 def test_model_ssd_chunked_matches_sequential_ref():
@@ -117,6 +154,27 @@ def test_model_ssd_chunked_matches_sequential_ref():
     Bm = jnp.asarray(rng.normal(size=(B, S, N)), jnp.float32) * 0.5
     Cm = jnp.asarray(rng.normal(size=(B, S, N)), jnp.float32) * 0.5
     y, final = ssd_chunked(x, dt, A, Bm, Cm, chunk=32)
+    yref, fref = ssd_ref(x, dt, A, Bm, Cm)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yref),
+                               atol=2e-4, rtol=1e-3)
+    np.testing.assert_allclose(np.asarray(final), np.asarray(fref),
+                               atol=2e-4, rtol=1e-3)
+
+
+def test_model_ssd_chunked_is_finite_under_strong_decay():
+    """A whole 256-position chunk at Mamba-2's strongest decay (A = -16,
+    dt = 0.1: the cumulative log-decay falls by 410 across the chunk) stays
+    finite and exact: above the diagonal exp(cum_i - cum_j) would overflow,
+    and the causal mask must act before the exp, not after."""
+    from repro.models.ssm import ssd_chunked
+    rng = np.random.default_rng(4)
+    B, S, H, P, N = 1, 256, 2, 4, 8
+    x = jnp.asarray(rng.normal(size=(B, S, H, P)), jnp.float32)
+    dt = jnp.full((B, S, H), 0.1, jnp.float32)
+    A = jnp.asarray([-16.0, -1.0], jnp.float32)
+    Bm = jnp.asarray(rng.normal(size=(B, S, N)), jnp.float32)
+    Cm = jnp.asarray(rng.normal(size=(B, S, N)), jnp.float32)
+    y, final = ssd_chunked(x, dt, A, Bm, Cm, chunk=256)
     yref, fref = ssd_ref(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yref),
                                atol=2e-4, rtol=1e-3)

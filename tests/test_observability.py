@@ -370,6 +370,29 @@ def test_engine_traces_match_request_timestamps_exactly(tiny, tmp_path):
     assert obs.timeline.num_events == 0 and obs.slo.report() == {}
 
 
+def test_reset_stats_freezes_the_setup_heap(tiny):
+    """reset_stats is the warm-up boundary: what set-up left alive moves
+    out of the cyclic collector's reach (gc.freeze), so a full collection
+    inside the serving loop does not walk it."""
+    import gc
+    cfg, params = tiny
+    engine = Engine(cfg, params,
+                    EngineConfig(num_slots=2, num_pages=32, page_size=8,
+                                 max_prompt_len=16, max_new_tokens=4,
+                                 token_budget=16, kv_dtype="float32",
+                                 compute_dtype="float32"))
+    gc.unfreeze()
+    setup = [engine]                  # a container alive across the boundary
+    engine.reset_stats()
+    try:
+        assert gc.get_freeze_count() > 0
+        assert not any(o is setup for o in gc.get_objects())
+        after = []                    # allocated once serving has begun
+        assert any(o is after for o in gc.get_objects())
+    finally:
+        gc.unfreeze()
+
+
 def test_engine_stats_attribute_shim(tiny):
     cfg, params = tiny
     engine = Engine(cfg, params,

@@ -610,7 +610,7 @@ def test_engine_rejects_unsupported_arch():
     from repro.configs.base import get_model_config, reduced
     from repro.serving import Engine, EngineConfig
 
-    cfg = reduced(get_model_config("mamba2-2.7b"))
+    cfg = reduced(get_model_config("whisper-base"))   # encoder-decoder
     with pytest.raises(ValueError):
         Engine(cfg, params=None, ecfg=EngineConfig())
 

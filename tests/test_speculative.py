@@ -197,6 +197,31 @@ def test_temperature_nonspec_path_unchanged_by_plumbing(params):
 # ---------------------------------------------------------------------------
 # budget accounting + validation
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("spec_k", [0, 4])
+def test_tick_pulls_accepted_counts_only_where_a_slot_drafts(
+        params, draft, spec_k, monkeypatch):
+    """A tick's one host pull carries the accepted counts only where a
+    slot drafted (only the speculative commit reads them); any other tick
+    pulls the sampled tokens alone, one device-to-host transfer."""
+    import sys
+    real, pulls = jax.device_get, []
+
+    def spy(x):
+        if sys._getframe(1).f_code.co_name == "step":
+            pulls.append(isinstance(x, tuple))
+        return real(x)
+
+    eng = mk(params, spec_k=spec_k, draft=draft if spec_k else None)
+    monkeypatch.setattr(jax, "device_get", spy)
+    out = drain(eng, prompts((7, 13, 5)))
+    assert len(pulls) == eng.steps
+    if spec_k:
+        assert any(pulls) and not all(pulls)    # prefill ticks draft nothing
+        assert out == drain(mk(params), prompts((7, 13, 5)))
+    else:
+        assert not any(pulls)
+
+
 def test_speculative_budget_split():
     # each decode slot costs its pending token; the rest splits across
     # speculating slots, clamped to k and floored at plain decode
